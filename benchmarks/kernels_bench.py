@@ -151,26 +151,25 @@ def kernel_records(shapes, smoke: bool = False):
             records.append(rec)
 
         if smoke:
-            # exercise the actual Pallas kernels (interpret mode)
+            # exercise the actual Pallas kernels (interpreted off-TPU)
             from repro.kernels.assignment import assignment_pallas
             from repro.kernels.fused_lloyd import fused_lloyd_pallas
             from repro.kernels.update import update_pallas
             w = jnp.ones((n,), jnp.float32)
+            wall_path = ("pallas_interpret" if tiles.interpret_default()
+                         else "pallas_tpu")
             for variant, fn in (
-                    ("pallas.fused", lambda: fused_lloyd_pallas(
-                        x, c, interpret=True)),
+                    ("pallas.fused", lambda: fused_lloyd_pallas(x, c)),
                     ("pallas.fused_weighted", lambda: fused_lloyd_pallas(
-                        x, c, w, interpret=True)),
-                    ("pallas.assignment", lambda: assignment_pallas(
-                        x, c, interpret=True)),
+                        x, c, w)),
+                    ("pallas.assignment", lambda: assignment_pallas(x, c)),
                     ("pallas.update", lambda: update_pallas(
-                        x, jnp.zeros((n,), jnp.int32), k, w=w,
-                        interpret=True))):
+                        x, jnp.zeros((n,), jnp.int32), k, w=w))):
                 _, t = timed(lambda fn=fn: fn(), warmup=1, reps=1)
                 base = variant.split(".", 1)[1].replace("_weighted", "")
                 records.append({"variant": variant, "n": n, "d": d, "k": k,
                                 "wall_us": t * 1e6,
-                                "wall_path": "pallas_interpret",
+                                "wall_path": wall_path,
                                 "skipped_tile_frac": None, "phase": None,
                                 "layout": None,
                                 **analyze(n, d, k, base)})
@@ -223,7 +222,7 @@ def bounds_records(group_size=8, refine_steps=4):
     *drops* as bytes shrink slower than flops."""
     from repro.core.backends.bounds import extract_stats
 
-    wall_path = ("pallas_interpret" if jax.default_backend() != "tpu"
+    wall_path = ("pallas_interpret" if tiles.interpret_default()
                  else "pallas_tpu")
     records = []
     for layout in BOUNDS_LAYOUTS:
@@ -301,7 +300,7 @@ def solver_records(max_iter=12):
     # converges in one iteration, leaving no post-accept phase to measure
     c0 = x[np.random.default_rng(11).choice(n, k, replace=False)]
     cfg = KMeansConfig(k=k, max_iter=max_iter)
-    wall_path = ("pallas_interpret" if jax.default_backend() != "tpu"
+    wall_path = ("pallas_interpret" if tiles.interpret_default()
                  else "pallas_tpu")
     records = []
     for layout, reorder in (("interleaved", False),

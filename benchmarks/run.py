@@ -1,6 +1,7 @@
 """Benchmark orchestrator: one function per paper table + kernel/roofline
 reports.  Prints ``name,us_per_call,derived`` CSV (plus human-readable
-tables above each block).
+tables above each block).  Every block runs; a block that raises prints
+its traceback and the run exits non-zero.
 
     PYTHONPATH=src python -m benchmarks.run [--scale 0.05] [--fast]
 """
@@ -8,6 +9,7 @@ tables above each block).
 from __future__ import annotations
 
 import argparse
+import sys
 import traceback
 
 
@@ -31,11 +33,12 @@ def main() -> None:
     from benchmarks import kernels_bench, roofline, table2_dynamic_m, \
         table3_vs_lloyd
     from repro.data.synthetic import DATASETS
+    from repro.runtime.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     datasets = list(DATASETS)[:6] if args.fast else None
 
-    print("# === Table 2: fixed vs dynamic m ===", flush=True)
-    try:
+    def table2():
         s2 = table2_dynamic_m.run(scale=args.scale, datasets=datasets,
                                   backend=args.backend)
         n = s2["total"]
@@ -46,11 +49,8 @@ def main() -> None:
         print(f"table2.fixed_m5,{mean('fixed_m5')*1e6:.1f},")
         print(f"table2.dynamic_m5,{mean('dyn_m5')*1e6:.1f},"
               f"wins={s2['wins_dynamic_m5']}/{n}")
-    except Exception:
-        traceback.print_exc()
 
-    print("# === Table 3: AA-KMeans vs Lloyd ===", flush=True)
-    try:
+    def table3():
         s3 = table3_vs_lloyd.run(scale=args.scale, datasets=datasets,
                                  backend=args.backend)
         mean_l = sum(c["lloyd_time_s"] for c in s3["cases"]) / s3["total"]
@@ -61,54 +61,49 @@ def main() -> None:
               f"iter_wins={s3['iter_wins']}/{s3['total']};"
               f"mean_time_decrease={s3['mean_time_decrease']:.1%};"
               f"mse_parity={s3['mse_parity']}/{s3['total']}")
-    except Exception:
-        traceback.print_exc()
 
-    print("# === Batched engine: multi-restart + grid sweep ===", flush=True)
-    try:
+    def batched():
         from benchmarks import batched_sweep
         batched_sweep.main(backend=args.backend)
-    except Exception:
-        traceback.print_exc()
 
-    print("# === Checkpoint segmentation overhead ===", flush=True)
-    try:
+    def checkpoint():
         from benchmarks import checkpoint_bench
         checkpoint_bench.main(
             ["--json", "--checkpoint-every", str(args.checkpoint_every)]
             + (["--smoke"] if args.fast else []))
-    except Exception:
-        traceback.print_exc()
 
-    print("# === Serving: closure-index recall vs latency ===", flush=True)
-    try:
+    def serving():
         from benchmarks import serving_bench
         serving_bench.main(["--json"] + (["--smoke"] if args.fast else []))
-    except Exception:
-        traceback.print_exc()
 
-    print("# === Hierarchy: flat vs divide-and-conquer ===", flush=True)
-    try:
+    def hierarchy():
         from benchmarks import hierarchy_bench
         hierarchy_bench.main(["--json"] + (["--smoke"] if args.fast else []))
-    except Exception:
-        traceback.print_exc()
 
-    print("# === Kernel roofline (fused vs split Lloyd pass) ===",
-          flush=True)
-    try:
+    blocks = [
+        ("Table 2: fixed vs dynamic m", table2),
+        ("Table 3: AA-KMeans vs Lloyd", table3),
+        ("Batched engine: multi-restart + grid sweep", batched),
+        ("Checkpoint segmentation overhead", checkpoint),
+        ("Serving: closure-index recall vs latency", serving),
+        ("Hierarchy: flat vs divide-and-conquer", hierarchy),
         # empty argv: run.py's own CLI args must not leak into the
         # benchmark's parser; the orchestrator always emits the JSON seed
-        kernels_bench.main(["--json"])
-    except Exception:
-        traceback.print_exc()
-
-    print("# === LM roofline table (from dry-run artifacts) ===",
-          flush=True)
-    try:
-        roofline.main()
-    except Exception:
-        traceback.print_exc()
+        ("Kernel roofline (fused vs split Lloyd pass)",
+         lambda: kernels_bench.main(["--json"])),
+        ("LM roofline table (from dry-run artifacts)", roofline.main),
+    ]
+    failed = []
+    for title, block in blocks:
+        print(f"# === {title} ===", flush=True)
+        try:
+            block()
+        except Exception:        # report every block, then fail the run
+            traceback.print_exc()
+            failed.append(title)
+    if failed:
+        print(f"# FAILED blocks: {'; '.join(failed)}", file=sys.stderr)
+        sys.exit(1)
 
 
 if __name__ == "__main__":

@@ -44,6 +44,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro.core.lloyd import MATMUL_PRECISION
+
 
 @dataclasses.dataclass(frozen=True)
 class AAConfig:
@@ -173,8 +175,9 @@ def aa_push_and_solve(state: AAState, f: jax.Array, g: jax.Array,
 
     # Normal equations over masked columns:  (A A^T + lam I) theta = A f
     a_mask = jnp.where(active[:, None], dF, 0.0)
-    gram = a_mask @ a_mask.T                          # (mbar, mbar)
-    rhs = a_mask @ f                                  # (mbar,)
+    gram = jnp.matmul(a_mask, a_mask.T,
+                      precision=MATMUL_PRECISION)     # (mbar, mbar)
+    rhs = jnp.matmul(a_mask, f, precision=MATMUL_PRECISION)   # (mbar,)
     lam = cfg.ridge * (jnp.trace(gram) + 1.0)
     eye = jnp.eye(mbar, dtype=f.dtype)
     # Identity rows/cols for inactive entries keep the solve well-posed.
@@ -184,7 +187,8 @@ def aa_push_and_solve(state: AAState, f: jax.Array, g: jax.Array,
     theta = jnp.where(active, theta, 0.0)
 
     dg_mask = jnp.where(active[:, None], dG, 0.0)
-    c_next = g - theta @ dg_mask                      # Algorithm 1 line 19
+    c_next = g - jnp.matmul(theta, dg_mask,
+                            precision=MATMUL_PRECISION)   # Alg. 1 line 19
     # m_t == 0 -> plain Lloyd iterate (theta is all zero already, but be
     # explicit so a zero window is exactly un-accelerated).
     c_next = jnp.where(m_t > 0, c_next, g)

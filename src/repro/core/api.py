@@ -33,12 +33,11 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.core import serialize
 from repro.core.anderson import AAConfig
 from repro.core.distributed import (make_distributed_kmeans_batched,
                                     make_distributed_kmeans_minibatch,
-                                    shard_dataset)
+                                    shard_dataset, shard_map)
 from repro.core.init_schemes import batched_init, make_init
 from repro.core.kmeans import (KMeansConfig, KMeansResult,
                                aa_kmeans_batched, aa_kmeans_minibatch,
@@ -74,7 +73,7 @@ def _mesh_rows_apply(model, x, kind, fn, extras=()):
     cache_key = (kind, model.mesh, axes, model.backend)
     run = cache.get(cache_key)
     if run is None:
-        run = cache[cache_key] = jax.jit(compat.shard_map(
+        run = cache[cache_key] = jax.jit(shard_map(
             fn, mesh=model.mesh,
             in_specs=(P(axes), P()) + (P(),) * len(extras),
             out_specs=P(axes)))

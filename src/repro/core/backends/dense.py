@@ -98,10 +98,12 @@ def _batched_step(precision: Precision):
         c_sq = jnp.sum(cc * cc, axis=-1)                       # (R, K)
         x_sq = jnp.sum(xc * xc, axis=-1)                       # (N,)|(R,N)
         if x.ndim == 2:
-            cross = jnp.einsum("nd,rkd->rnk", xc, cc)
+            cross = jnp.einsum("nd,rkd->rnk", xc, cc,
+                               precision=lloyd.MATMUL_PRECISION)
             x_term = x_sq[None, :, None]
         else:
-            cross = jnp.einsum("rnd,rkd->rnk", xc, cc)
+            cross = jnp.einsum("rnd,rkd->rnk", xc, cc,
+                               precision=lloyd.MATMUL_PRECISION)
             x_term = x_sq[:, :, None]
         d2 = jnp.maximum(x_term - 2.0 * cross + c_sq[:, None, :], 0.0)
         labels = jnp.argmin(d2, axis=-1).astype(jnp.int32)     # (R, N)
@@ -114,9 +116,11 @@ def _batched_step(precision: Precision):
             onehot = onehot * w.astype(precision.accum_dtype)[:, :, None]
         xa = x.astype(precision.accum_dtype)
         if x.ndim == 2:
-            sums = jnp.einsum("rnk,nd->rkd", onehot, xa)
+            sums = jnp.einsum("rnk,nd->rkd", onehot, xa,
+                              precision=lloyd.MATMUL_PRECISION)
         else:
-            sums = jnp.einsum("rnk,rnd->rkd", onehot, xa)
+            sums = jnp.einsum("rnk,rnd->rkd", onehot, xa,
+                              precision=lloyd.MATMUL_PRECISION)
         counts = jnp.sum(onehot, axis=1)                       # (R, K)
         if w is None:
             energy = jnp.sum(mind, axis=-1)

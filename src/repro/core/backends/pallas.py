@@ -39,16 +39,13 @@ whose bound says no row can improve.  The drift maintenance between step
 calls is the same triangle-inequality algebra as the elkan/yinyang CPU
 backends, so it stays valid across accepted Anderson jumps and reverts.
 
-On non-TPU hosts the kernels execute in interpret mode (correctness
-path); the TPU lowering is exercised by the dry-run entrypoints.
-``REPRO_PALLAS_INTERPRET=1`` forces interpret mode everywhere — the
-``test.sh --interpret`` tier uses it to run the kernel suite through
-`pallas_call(interpret=True)` on any host.
+The kernels decide their own execution mode (`tiles.interpret_default`):
+compiled by Mosaic on a TPU, interpreted on any other host.  The TPU
+lowering at real widths is checked without a chip by
+tests/test_tpu_compile.py.
 """
 
 from __future__ import annotations
-
-import os
 
 import jax
 import jax.numpy as jnp
@@ -69,19 +66,13 @@ FUSED_VMEM_BYTES = tiles.DEFAULT_VMEM_BUDGET
 FUSED_MAX_KD = FUSED_VMEM_BYTES // 4
 
 
-def _interpret() -> bool:
-    if os.environ.get("REPRO_PALLAS_INTERPRET", "") not in ("", "0"):
-        return True
-    return jax.default_backend() != "tpu"
-
-
 def _assign_fn(x, c):
-    labels, mind = assignment_pallas(x, c, interpret=_interpret())
+    labels, mind = assignment_pallas(x, c)
     return AssignResult(labels, mind)
 
 
 def _stats_fn(x, labels, k):
-    return update_pallas(x, labels, k, interpret=_interpret())
+    return update_pallas(x, labels, k)
 
 
 def _pack(precision: Precision, labels, mind, sums, counts, energy=None):
@@ -103,10 +94,10 @@ def _split_step(precision: Precision):
     def step_fn(x, c, k, carry):
         xc = precision.compute_cast(x)
         cc = precision.compute_cast(c)
-        labels, mind = assignment_pallas(xc, cc, interpret=_interpret())
+        labels, mind = assignment_pallas(xc, cc)
         # policy: the stats matmul reads the same compute-cast X as the
         # distance pass (one X stream, one dtype), accumulating in f32
-        sums, counts = update_pallas(xc, labels, k, interpret=_interpret())
+        sums, counts = update_pallas(xc, labels, k)
         return _pack(precision, labels, mind, sums, counts), carry
     return step_fn
 
@@ -125,8 +116,8 @@ def _split_batched(precision: Precision):
                     x, cs, w, carries)
         xc = precision.compute_cast(x)
         cc = precision.compute_cast(cs)
-        labels, mind = assignment_pallas(xc, cc, interpret=_interpret())
-        sums, counts = update_pallas(xc, labels, k, interpret=_interpret())
+        labels, mind = assignment_pallas(xc, cc)
+        sums, counts = update_pallas(xc, labels, k)
         return _pack(precision, labels, mind, sums, counts), carries
     return batched_step_fn
 
@@ -135,9 +126,8 @@ def _split_minibatch(precision: Precision):
     def minibatch_step_fn(x, c, k, w, carry):
         xc = precision.compute_cast(x)
         cc = precision.compute_cast(c)
-        labels, mind = assignment_pallas(xc, cc, interpret=_interpret())
-        sums, counts = update_pallas(xc, labels, k, w=w,
-                                     interpret=_interpret())
+        labels, mind = assignment_pallas(xc, cc)
+        sums, counts = update_pallas(xc, labels, k, w=w)
         acc = precision.accum_dtype
         energy = jnp.sum(mind.astype(acc) * w.astype(acc))
         return _pack(precision, labels, mind, sums, counts, energy), carry
@@ -163,7 +153,7 @@ def _fused_step(precision: Precision):
         xc = precision.compute_cast(x)
         cc = precision.compute_cast(c)
         labels, mind, sums, counts, energy = fused_lloyd_pallas(
-            xc, cc, interpret=_interpret())
+            xc, cc)
         return _pack(precision, labels, mind, sums, counts, energy), carry
     return step_fn
 
@@ -173,7 +163,7 @@ def _fused_batched(precision: Precision):
         xc = precision.compute_cast(x)
         cc = precision.compute_cast(cs)
         labels, mind, sums, counts, energy = fused_lloyd_pallas(
-            xc, cc, w, interpret=_interpret())
+            xc, cc, w)
         return _pack(precision, labels, mind, sums, counts, energy), carries
     return batched_step_fn
 
@@ -183,7 +173,7 @@ def _fused_minibatch(precision: Precision):
         xc = precision.compute_cast(x)
         cc = precision.compute_cast(c)
         labels, mind, sums, counts, energy = fused_lloyd_pallas(
-            xc, cc, w, interpret=_interpret())
+            xc, cc, w)
         return _pack(precision, labels, mind, sums, counts, energy), carry
     return minibatch_step_fn
 
@@ -245,7 +235,7 @@ def fused_bounds_backend(precision: Precision = DEFAULT_PRECISION,
             if batched else _prep
         lb_sq, ub_sq = prep(labels0, upper, lower, c_last, cf, g, gs)
         labels, mind, sums, counts, energy, gmin_sq, skipped = \
-            fused_lloyd_pallas(xc, cc, w, tk=gs, interpret=_interpret(),
+            fused_lloyd_pallas(xc, cc, w, tk=gs,
                                bounds=(labels0, lb_sq, ub_sq))
         u_new = jnp.sqrt(mind)
         lower_new = jnp.sqrt(gmin_sq)

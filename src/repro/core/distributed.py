@@ -34,7 +34,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.core import kmeans as KM
 from repro.core import lloyd, serialize
 from repro.core.backends import Backend, distribute
@@ -43,6 +42,20 @@ from repro.core.kmeans import (KMeansConfig, KMeansResult, aa_kmeans,
                                resolve_backend, select_best)
 from repro.core.lloyd import LloydOps
 from repro.core.minibatch import MiniBatchConfig, MiniBatchResult
+from repro.kernels import tiles
+
+
+def shard_map(f, /, **kwargs):
+    """`jax.shard_map` for the solver's programs.
+
+    The varying-manual-axes checker stays on wherever the Pallas kernels
+    compile (a TPU): their outputs declare how they vary
+    (`kernels.tiles.out_struct`).  Off-TPU the kernels run in the Pallas
+    interpreter, whose grid loop slices the sharded operands with
+    replicated grid indices, a mix JAX's checker refuses; there the check
+    is off.  The check changes no value, only what is verified."""
+    kwargs.setdefault("check_vma", not tiles.interpret_default())
+    return jax.shard_map(f, **kwargs)
 
 
 def distributed_lloyd_ops(data_axes: Sequence[str],
@@ -188,7 +201,7 @@ def make_distributed_kmeans(mesh: jax.sharding.Mesh, cfg: KMeansConfig,
     rep = P()
 
     @functools.partial(
-        compat.shard_map, mesh=mesh,
+        shard_map, mesh=mesh,
         in_specs=(x_spec, rep),
         out_specs=KMeansResult(centroids=rep, labels=x_spec, energy=rep,
                                n_iter=rep, n_accepted=rep, converged=rep))
@@ -226,10 +239,10 @@ def make_distributed_kmeans(mesh: jax.sharding.Mesh, cfg: KMeansConfig,
         x_local = jax.ShapeDtypeStruct((x.shape[0] // n_shards, x.shape[1]),
                                        x.dtype)
         specs = loop_state_specs(local, cfg, x_local, c0, axes)
-        init = jax.jit(compat.shard_map(
+        init = jax.jit(shard_map(
             lambda xl, cc: KM._init_state(xl, cc, cfg, ops),
             mesh=mesh, in_specs=(x_spec, rep), out_specs=specs))
-        seg = jax.jit(compat.shard_map(
+        seg = jax.jit(shard_map(
             lambda xl, st, end: KM._run_segment(xl, st, end, cfg=cfg,
                                                 backend=ops),
             mesh=mesh, in_specs=(x_spec, specs, rep), out_specs=specs))
@@ -305,7 +318,7 @@ def make_distributed_kmeans_batched(mesh: jax.sharding.Mesh,
     lab_spec = P(None, axes)      # (R, N): restart axis replicated
 
     @functools.partial(
-        compat.shard_map, mesh=mesh,
+        shard_map, mesh=mesh,
         in_specs=(x_spec, rep),
         out_specs=KMeansResult(centroids=rep, labels=lab_spec, energy=rep,
                                n_iter=rep, n_accepted=rep, converged=rep))
@@ -349,7 +362,7 @@ def make_distributed_kmeans_minibatch(mesh: jax.sharding.Mesh,
     rep = P()
 
     @functools.partial(
-        compat.shard_map, mesh=mesh,
+        shard_map, mesh=mesh,
         in_specs=(chunk_spec, chunk_spec, val_spec, rep, rep),
         out_specs=MiniBatchResult(centroids=rep, energy=rep, n_steps=rep,
                                   n_accepted=rep))

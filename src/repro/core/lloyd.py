@@ -34,6 +34,12 @@ from typing import Callable, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
+# f32 matmuls run at f32 on every platform: a TPU's DEFAULT precision
+# rounds f32 operands to bf16, which moves near-tie assignments and the
+# Anderson solve away from the CPU result.  bf16 operands are exact
+# either way.
+MATMUL_PRECISION = jax.lax.Precision.HIGHEST
+
 
 class AssignResult(NamedTuple):
     labels: jax.Array      # (N,) int32 — index of the closest centroid
@@ -52,7 +58,7 @@ def pairwise_sqdist(x: jax.Array, c: jax.Array) -> jax.Array:
     """
     x_sq = jnp.sum(x * x, axis=-1, keepdims=True)          # (N,1)
     c_sq = jnp.sum(c * c, axis=-1)                         # (K,)
-    cross = x @ c.T                                        # (N,K) — MXU
+    cross = jnp.matmul(x, c.T, precision=MATMUL_PRECISION)  # (N,K) — MXU
     return jnp.maximum(x_sq - 2.0 * cross + c_sq[None, :], 0.0)
 
 

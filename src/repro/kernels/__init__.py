@@ -1,6 +1,6 @@
 """Pallas TPU kernel engine v2 (DESIGN.md §Kernels-v2).
 
-    tiles.py       — VMEM-budget tile chooser + Mosaic dimension hints
+    tiles.py       — tile chooser, VMEM limits, execution mode, vma
     assignment.py  — tiled argmin-distance kernel (Eq. 3)
     update.py      — weighted one-hot segment-sum kernel (Eq. 4)
     fused_lloyd.py — single-pass fused step: one X read per iteration,

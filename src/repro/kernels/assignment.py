@@ -41,13 +41,14 @@ def _assignment_kernel(x_ref, c_ref, csq_ref, labels_ref, mind_ref, *,
 
     x = x_ref[...]
     x = x.reshape(x.shape[-2], x.shape[-1])            # (TN, d)
-    c = c_ref[...].reshape(c_ref.shape[-2], c_ref.shape[-1])   # (TK, d)
-    csq = csq_ref[...].reshape(1, -1)                  # (1, TK)
+    c = c_ref[0]                                       # (TK, d)
+    csq = csq_ref[0]                                   # (1, TK)
 
     xf = x.astype(jnp.float32)
     xsq = jnp.sum(xf * xf, axis=-1, keepdims=True)     # (TN, 1)
     cross = jax.lax.dot_general(
         x, c, (((1,), (1,)), ((), ())),
+        precision=tiles.mxu_precision(x, c),
         preferred_element_type=jnp.float32)            # (TN, TK) on the MXU
     dist = jnp.maximum(xsq - 2.0 * cross + csq, 0.0)
 
@@ -85,15 +86,19 @@ def _assignment_call(x, cs, *, tn: int, tk: int, interpret: bool):
         mask = jnp.arange(cp.shape[-2]) >= k
         csq = jnp.where(mask[None, :],
                         jnp.float32(jnp.finfo(jnp.float32).max), csq)
+    csq = csq[:, None, :]                              # (R, 1, Kp) lane-major
 
     np_, dp = xp.shape[-2], xp.shape[-1]
     kp = cp.shape[-2]
+    if not interpret:
+        tiles.check_tiles(tn, np_, tk, kp)
     grid = (r, np_ // tn, kp // tk)
 
     if x_batched:
         x_spec = pl.BlockSpec((1, tn, dp), lambda rr, i, j: (rr, i, 0))
     else:
         x_spec = pl.BlockSpec((tn, dp), lambda rr, i, j: (i, 0))
+    row = pl.BlockSpec((1, 1, tn), lambda rr, i, j: (rr, 0, i))
 
     labels, mind = pl.pallas_call(
         functools.partial(_assignment_kernel, tk=tk),
@@ -101,24 +106,24 @@ def _assignment_call(x, cs, *, tn: int, tk: int, interpret: bool):
         in_specs=[
             x_spec,
             pl.BlockSpec((1, tk, dp), lambda rr, i, j: (rr, j, 0)),
-            pl.BlockSpec((1, tk), lambda rr, i, j: (rr, j)),
+            pl.BlockSpec((1, 1, tk), lambda rr, i, j: (rr, 0, j)),
         ],
-        out_specs=[
-            pl.BlockSpec((1, tn), lambda rr, i, j: (rr, i)),
-            pl.BlockSpec((1, tn), lambda rr, i, j: (rr, i)),
-        ],
+        out_specs=[row, row],
         out_shape=[
-            jax.ShapeDtypeStruct((r, np_), jnp.int32),
-            jax.ShapeDtypeStruct((r, np_), jnp.float32),
+            tiles.out_struct((r, 1, np_), jnp.int32, xp, cp),
+            tiles.out_struct((r, 1, np_), jnp.float32, xp, cp),
         ],
-        **tiles.dimension_semantics("parallel", "parallel", "arbitrary"),
+        **tiles.compiler_params(
+            "assignment", ("parallel", "parallel", "arbitrary"), tn=tn,
+            tk=tk, kp=kp, dp=dp, itemsize=jnp.dtype(xp.dtype).itemsize,
+            interpret=interpret),
         interpret=interpret,
     )(xp, cp, csq)
-    return labels[:, :n], mind[:, :n]
+    return labels[:, 0, :n], mind[:, 0, :n]
 
 
 def assignment_pallas(x: jax.Array, c: jax.Array, *,
-                      tn=None, tk=None, interpret: bool = False,
+                      tn=None, tk=None, interpret=None,
                       vmem_bytes=None):
     """Nearest-centroid assignment via the Pallas kernel.
 
@@ -128,7 +133,8 @@ def assignment_pallas(x: jax.Array, c: jax.Array, *,
 
     Arbitrary N, K, d — inputs are padded to tile multiples; padded
     centroid rows get +inf squared norms so they are never selected.
-    Tile sizes default to the VMEM-budget chooser (`tiles.choose_tiles`).
+    Tile sizes default to the VMEM-budget chooser (`tiles.choose_tiles`);
+    ``interpret`` defaults to `tiles.interpret_default()`.
     """
     batched = c.ndim == 3
     if x.ndim == 3 and not batched:
@@ -143,7 +149,9 @@ def assignment_pallas(x: jax.Array, c: jax.Array, *,
                                     kind="assignment", vmem_bytes=vmem_bytes)
         tn = ct if tn is None else tn
         tk = ck if tk is None else tk
-    labels, mind = _assignment_call(x, cs, tn=tn, tk=tk, interpret=interpret)
+    labels, mind = _assignment_call(x, cs, tn=tn, tk=tk,
+                                    interpret=tiles.resolve_interpret(
+                                        interpret))
     if not batched:
         return labels[0], mind[0]
     return labels, mind

@@ -21,8 +21,13 @@ resident in VMEM for the whole k sweep —
 
 The (K, d) f32 stats accumulator stays VMEM-resident across the grid
 (k-tiling the *inputs* is what removed the old cliff; the accumulator's
-K·d·4 bytes is the remaining — much later — limit, priced by the
-`tiles.choose_tiles` footprint model).
+K·d·4 bytes, double-buffered, is the remaining — much later — limit:
+`tiles.compiler_params` raises Mosaic's VMEM limit to fit it, and
+refuses a shape past the chip's VMEM).
+
+Per-row and per-centroid vectors are lane-major, (R, 1, N) and
+(R, 1, K), so every block obeys Mosaic's (8, 128) rule for any R
+(`tiles` module docstring).
 
 Row weights are native: every row's contribution to sums/counts/energy is
 scaled by its weight, which (a) makes this kernel the streaming
@@ -52,25 +57,70 @@ from repro.kernels import tiles
 from repro.kernels.tiles import pad_to
 
 
-def _fused_kernel(x_ref, c_ref, csq_ref, w_ref,
-                  labels_ref, mind_ref, sums_ref, counts_ref, energy_ref,
-                  mind_s, amin_s, *, tk: int):
-    i = pl.program_id(1)          # X row tile (sequential: stats accumulate)
-    j = pl.program_id(2)          # centroid tile (minor: argmin sweep)
-    nk = pl.num_programs(2)
-
+def _distances(x_ref, c_ref, csq_ref):
+    """(x tile in f32, (TN, TK) squared distances) of one grid cell."""
     x = x_ref[...]
     x = x.reshape(x.shape[-2], x.shape[-1])            # (TN, d)
-    c = c_ref[...].reshape(c_ref.shape[-2], c_ref.shape[-1])   # (TK, d)
-    csq = csq_ref[...].reshape(1, -1)                  # (1, TK)
-
+    c = c_ref[0]                                       # (TK, d)
+    csq = csq_ref[0]                                   # (1, TK)
     xf = x.astype(jnp.float32)
     xsq = jnp.sum(xf * xf, axis=-1, keepdims=True)
     cross = jax.lax.dot_general(
         x, c, (((1,), (1,)), ((), ())),
+        precision=tiles.mxu_precision(x, c),
         preferred_element_type=jnp.float32)            # (TN, TK) on the MXU
-    dist = jnp.maximum(xsq - 2.0 * cross + csq, 0.0)
+    return xf, jnp.maximum(xsq - 2.0 * cross + csq, 0.0)
 
+
+def _emit(i, labels, mind, xf, w_ref, labels_ref, mind_ref, sums_ref,
+          counts_ref, energy_ref, *, tk: int, nk: int):
+    """Final k tile: the X tile's assignment is complete and the block is
+    still resident — write labels/min-dist and fold the weighted one-hot
+    stats and energy into the grid-resident accumulators."""
+    w = w_ref[...].reshape(-1)                         # (TN,) f32
+    labels_ref[...] = labels.reshape(labels_ref.shape)
+    mind_ref[...] = mind.reshape(mind_ref.shape)
+
+    @pl.when(i == 0)
+    def _init():
+        sums_ref[...] = jnp.zeros(sums_ref.shape, sums_ref.dtype)
+        counts_ref[...] = jnp.zeros(counts_ref.shape, counts_ref.dtype)
+        energy_ref[...] = jnp.zeros(energy_ref.shape, energy_ref.dtype)
+
+    tn = labels.shape[0]
+
+    def _accum_tile(off):
+        # Weighted one-hot restricted to one centroid tile keeps the
+        # intermediate at (TN, TK) — never (TN, K).
+        ks = jax.lax.broadcasted_iota(jnp.int32, (tn, tk), 1) + off
+        onehot = jnp.where(labels[:, None] == ks, w[:, None],
+                           jnp.float32(0.0))
+        psum = jax.lax.dot_general(
+            onehot, xf, (((0,), (0,)), ((), ())),
+            precision=tiles.mxu_precision(onehot, xf),
+            preferred_element_type=jnp.float32)        # (TK, d) on the MXU
+        sums_ref[0, pl.ds(off, tk), :] += psum
+        counts_ref[0, :, pl.ds(off, tk)] += jnp.sum(onehot, axis=0,
+                                                    keepdims=True)
+
+    if nk == 1:
+        _accum_tile(0)
+    else:
+        def body(jj, carry):
+            # tk is a lane multiple here, so the counts slice is aligned
+            _accum_tile(pl.multiple_of(jj * tk, tk))
+            return carry
+        jax.lax.fori_loop(0, nk, body, 0)
+    energy_ref[...] += jnp.sum(mind * w).reshape(energy_ref.shape)
+
+
+def _fused_kernel(x_ref, c_ref, csq_ref, w_ref,
+                  labels_ref, mind_ref, sums_ref, counts_ref, energy_ref,
+                  mind_s, amin_s, *, tk: int, nk: int):
+    i = pl.program_id(1)          # X row tile (sequential: stats accumulate)
+    j = pl.program_id(2)          # centroid tile (minor: argmin sweep)
+
+    xf, dist = _distances(x_ref, c_ref, csq_ref)
     local_min = jnp.min(dist, axis=-1)                 # (TN,)
     local_arg = jnp.argmin(dist, axis=-1).astype(jnp.int32) + j * tk
 
@@ -85,51 +135,23 @@ def _fused_kernel(x_ref, c_ref, csq_ref, w_ref,
         amin_s[...] = jnp.where(better, local_arg, amin_s[...])
         mind_s[...] = jnp.where(better, local_min, mind_s[...])
 
-    # Final k tile: the X tile's assignment is complete and the block is
-    # still resident — emit everything the step needs in the same pass.
     @pl.when(j == nk - 1)
-    def _emit():
-        labels = amin_s[...]
-        mind = mind_s[...]
-        w = w_ref[...].reshape(-1)                     # (TN,) f32
-        labels_ref[...] = labels.reshape(labels_ref.shape)
-        mind_ref[...] = mind.reshape(mind_ref.shape)
-
-        @pl.when(i == 0)
-        def _init():
-            sums_ref[...] = jnp.zeros(sums_ref.shape, sums_ref.dtype)
-            counts_ref[...] = jnp.zeros(counts_ref.shape, counts_ref.dtype)
-            energy_ref[...] = jnp.zeros(energy_ref.shape, energy_ref.dtype)
-
-        tn = labels.shape[0]
-
-        def _accum_tile(jj, carry):
-            # Weighted one-hot restricted to centroid tile jj keeps the
-            # intermediate at (TN, TK) — never (TN, K).
-            ks = jax.lax.broadcasted_iota(jnp.int32, (tn, tk), 1) + jj * tk
-            onehot = jnp.where(labels[:, None] == ks, w[:, None],
-                               jnp.float32(0.0))
-            psum = jax.lax.dot_general(
-                onehot, xf, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)    # (TK, d) on the MXU
-            sums_ref[0, pl.ds(jj * tk, tk), :] += psum
-            counts_ref[0, pl.ds(jj * tk, tk)] += jnp.sum(onehot, axis=0)
-            return carry
-
-        jax.lax.fori_loop(0, nk, _accum_tile, 0)
-        energy_ref[0, 0] += jnp.sum(mind * w)
+    def _final():
+        _emit(i, amin_s[...], mind_s[...], xf, w_ref, labels_ref, mind_ref,
+              sums_ref, counts_ref, energy_ref, tk=tk, nk=nk)
 
 
 def _fused_bounds_kernel(x_ref, c_ref, csq_ref, w_ref, lb_ref, ub_ref,
                          lab0_ref, labels_ref, mind_ref, sums_ref,
                          counts_ref, energy_ref, gmin_ref, skip_ref,
-                         mind_s, amin_s, *, tk: int):
+                         mind_s, amin_s, *, tk: int, nk: int):
     """The fused kernel with a per-(row-tile, k-tile) skip predicate.
 
-    Extra inputs per X row tile: the squared inclusive group lower bounds
-    lb (TN, G) — one lane per k-tile, G = num k tiles — the squared upper
-    bound ub (TN,), and the previous labels (TN,).  A k tile j is
-    computed only when ANY row of the tile has lb[:, j] <= ub (the
+    Extra inputs per X row tile: the squared inclusive lower bound of
+    the current k-tile's group, lb (1, TN) — the bounds are laid out
+    group-major, (R, G, 1, N), so the BlockSpec selects group j — the
+    squared upper bound ub (1, TN), and the previous labels (1, TN).  A
+    k tile j is computed only when ANY row of the tile has lb <= ub (the
     non-strict predicate is what guarantees a row's owner tile is always
     computed: lb_owner <= d(x, c_a)^2 <= ub); otherwise the whole
     distance block, and the C tile's use, are skipped under `pl.when`
@@ -140,20 +162,19 @@ def _fused_bounds_kernel(x_ref, c_ref, csq_ref, w_ref, lb_ref, ub_ref,
     it does not, ub was already exactly d(x, c_a)^2.
 
     Emits the fused kernel's five outputs plus the updated squared group
-    mins (TN, G) and a skipped-tile counter (one per restart), which the
-    wrapper normalises to a fraction of the (row-tile x k-tile) grid.
+    mins (group-major, like lb) and a skipped-tile counter (one per
+    restart), which the wrapper normalises to a fraction of the
+    (row-tile x k-tile) grid.
     """
     i = pl.program_id(1)
     j = pl.program_id(2)
-    nk = pl.num_programs(2)
 
-    lb = lb_ref[0, :, pl.ds(j, 1)].reshape(-1)                 # (TN,)
-    ub = ub_ref[...].reshape(-1)                               # (TN,)
-    pred = jnp.any(lb <= ub)
+    lb = lb_ref[0, 0]                                          # (1, TN)
+    pred = jnp.max(jnp.where(lb <= ub_ref[0], 1.0, 0.0)) > 0.0
 
     @pl.when(j == 0)
     def _seed():
-        mind_s[...] = ub
+        mind_s[...] = ub_ref[...].reshape(-1)
         amin_s[...] = lab0_ref[...].reshape(-1)
 
     @pl.when(jnp.logical_and(i == 0, j == 0))
@@ -162,152 +183,41 @@ def _fused_bounds_kernel(x_ref, c_ref, csq_ref, w_ref, lb_ref, ub_ref,
 
     @pl.when(pred)
     def _compute():
-        x = x_ref[...]
-        x = x.reshape(x.shape[-2], x.shape[-1])
-        c = c_ref[...].reshape(c_ref.shape[-2], c_ref.shape[-1])
-        csq = csq_ref[...].reshape(1, -1)
-        xf = x.astype(jnp.float32)
-        xsq = jnp.sum(xf * xf, axis=-1, keepdims=True)
-        cross = jax.lax.dot_general(
-            x, c, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dist = jnp.maximum(xsq - 2.0 * cross + csq, 0.0)
-
+        _, dist = _distances(x_ref, c_ref, csq_ref)
         local_min = jnp.min(dist, axis=-1)
         local_arg = jnp.argmin(dist, axis=-1).astype(jnp.int32) + j * tk
         # strict <: a tie keeps the seed (the row's standing assignment)
         better = local_min < mind_s[...]
         amin_s[...] = jnp.where(better, local_arg, amin_s[...])
         mind_s[...] = jnp.where(better, local_min, mind_s[...])
-        gmin_ref[0, :, pl.ds(j, 1)] = local_min[:, None]
+        gmin_ref[...] = local_min.reshape(gmin_ref.shape)
 
     @pl.when(jnp.logical_not(pred))
     def _skip():
-        skip_ref[0, 0] += 1.0
+        skip_ref[...] += jnp.ones(skip_ref.shape, skip_ref.dtype)
         # the drift-maintained bound stays the best known group min
-        gmin_ref[0, :, pl.ds(j, 1)] = lb[:, None]
+        gmin_ref[...] = lb_ref[...]
 
     @pl.when(j == nk - 1)
-    def _emit():
-        labels = amin_s[...]
-        mind = mind_s[...]
-        w = w_ref[...].reshape(-1)
-        labels_ref[...] = labels.reshape(labels_ref.shape)
-        mind_ref[...] = mind.reshape(mind_ref.shape)
-
-        @pl.when(i == 0)
-        def _init():
-            sums_ref[...] = jnp.zeros(sums_ref.shape, sums_ref.dtype)
-            counts_ref[...] = jnp.zeros(counts_ref.shape, counts_ref.dtype)
-            energy_ref[...] = jnp.zeros(energy_ref.shape, energy_ref.dtype)
-
+    def _final():
         x = x_ref[...]
         xf = x.reshape(x.shape[-2], x.shape[-1]).astype(jnp.float32)
-        tn = labels.shape[0]
-
-        def _accum_tile(jj, carry):
-            ks = jax.lax.broadcasted_iota(jnp.int32, (tn, tk), 1) + jj * tk
-            onehot = jnp.where(labels[:, None] == ks, w[:, None],
-                               jnp.float32(0.0))
-            psum = jax.lax.dot_general(
-                onehot, xf, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            sums_ref[0, pl.ds(jj * tk, tk), :] += psum
-            counts_ref[0, pl.ds(jj * tk, tk)] += jnp.sum(onehot, axis=0)
-            return carry
-
-        jax.lax.fori_loop(0, nk, _accum_tile, 0)
-        energy_ref[0, 0] += jnp.sum(mind * w)
+        _emit(i, amin_s[...], mind_s[...], xf, w_ref, labels_ref, mind_ref,
+              sums_ref, counts_ref, energy_ref, tk=tk, nk=nk)
 
 
-@functools.partial(jax.jit, static_argnames=("tn", "tk", "interpret"))
-def _fused_bounds_call(x, cs, w, lab0, lb_sq, ub_sq, *, tn: int, tk: int,
-                       interpret: bool):
-    r, k, d = cs.shape
-    n = x.shape[-2]
-    x_batched = x.ndim == 3
+def _rows(v, tn, value=0.0):
+    """(..., N) per-row vector -> lane-major (B, 1, Np) kernel operand."""
+    vp = pad_to(v, -1, tn, value=value)
+    return vp.reshape((-1, 1, vp.shape[-1]))
 
+
+def _operands(x, cs, w, *, tn: int, tk: int):
+    """Padded operands and BlockSpecs shared by both fused kernels."""
+    k = cs.shape[-2]
     xp = pad_to(pad_to(x, -2, tn), -1, tiles.LANE)
     cp = pad_to(pad_to(cs, -2, tk), -1, tiles.LANE)
-    wp = pad_to(w, -1, tn)
-    w_batched = w.ndim == 2
-    fmax = jnp.float32(jnp.finfo(jnp.float32).max)
-    # padding rows must never force a tile's computation: their lower
-    # bound is +max and their upper bound 0, so lb <= ub is always false
-    lab0p = pad_to(lab0, -1, tn)
-    lbp = pad_to(lb_sq, -2, tn, value=fmax)
-    ubp = pad_to(ub_sq, -1, tn, value=0.0)
-
-    cpf = cp.astype(jnp.float32)
-    csq = jnp.sum(cpf * cpf, axis=-1)
-    if cp.shape[-2] != k:
-        mask = jnp.arange(cp.shape[-2]) >= k
-        csq = jnp.where(mask[None, :], fmax, csq)
-
-    np_, dp = xp.shape[-2], xp.shape[-1]
-    kp = cp.shape[-2]
-    g = kp // tk
-    assert lbp.shape[-1] == g, (lbp.shape, g)
-    grid = (r, np_ // tn, kp // tk)
-
-    if x_batched:
-        x_spec = pl.BlockSpec((1, tn, dp), lambda rr, i, j: (rr, i, 0))
-    else:
-        x_spec = pl.BlockSpec((tn, dp), lambda rr, i, j: (i, 0))
-    if w_batched:
-        w_spec = pl.BlockSpec((1, tn), lambda rr, i, j: (rr, i))
-    else:
-        w_spec = pl.BlockSpec((tn,), lambda rr, i, j: (i,))
-
-    return pl.pallas_call(
-        functools.partial(_fused_bounds_kernel, tk=tk),
-        grid=grid,
-        in_specs=[
-            x_spec,
-            pl.BlockSpec((1, tk, dp), lambda rr, i, j: (rr, j, 0)),
-            pl.BlockSpec((1, tk), lambda rr, i, j: (rr, j)),
-            w_spec,
-            pl.BlockSpec((1, tn, g), lambda rr, i, j: (rr, i, 0)),
-            pl.BlockSpec((1, tn), lambda rr, i, j: (rr, i)),
-            pl.BlockSpec((1, tn), lambda rr, i, j: (rr, i)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, tn), lambda rr, i, j: (rr, i)),
-            pl.BlockSpec((1, tn), lambda rr, i, j: (rr, i)),
-            pl.BlockSpec((1, kp, dp), lambda rr, i, j: (rr, 0, 0)),
-            pl.BlockSpec((1, kp), lambda rr, i, j: (rr, 0)),
-            pl.BlockSpec((1, 1), lambda rr, i, j: (rr, 0)),
-            pl.BlockSpec((1, tn, g), lambda rr, i, j: (rr, i, 0)),
-            pl.BlockSpec((1, 1), lambda rr, i, j: (rr, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((r, np_), jnp.int32),
-            jax.ShapeDtypeStruct((r, np_), jnp.float32),
-            jax.ShapeDtypeStruct((r, kp, dp), jnp.float32),
-            jax.ShapeDtypeStruct((r, kp), jnp.float32),
-            jax.ShapeDtypeStruct((r, 1), jnp.float32),
-            jax.ShapeDtypeStruct((r, np_, g), jnp.float32),
-            jax.ShapeDtypeStruct((r, 1), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((tn,), jnp.float32),
-            pltpu.VMEM((tn,), jnp.int32),
-        ],
-        **tiles.dimension_semantics("parallel", "arbitrary", "arbitrary"),
-        interpret=interpret,
-    )(xp, cp, csq, wp, lbp, ubp, lab0p)
-
-
-@functools.partial(jax.jit, static_argnames=("tn", "tk", "interpret"))
-def _fused_call(x, cs, w, *, tn: int, tk: int, interpret: bool):
-    r, k, d = cs.shape
-    n = x.shape[-2]
-    x_batched = x.ndim == 3
-
-    xp = pad_to(pad_to(x, -2, tn), -1, tiles.LANE)
-    cp = pad_to(pad_to(cs, -2, tk), -1, tiles.LANE)
-    wp = pad_to(w, -1, tn)           # tile-padding rows weigh 0 -> inert
-    w_batched = w.ndim == 2
+    wp = _rows(w, tn)            # tile-padding rows weigh 0 -> inert
 
     cpf = cp.astype(jnp.float32)
     csq = jnp.sum(cpf * cpf, axis=-1)                  # (R, Kp)
@@ -316,56 +226,107 @@ def _fused_call(x, cs, w, *, tn: int, tk: int, interpret: bool):
         mask = jnp.arange(cp.shape[-2]) >= k
         csq = jnp.where(mask[None, :],
                         jnp.float32(jnp.finfo(jnp.float32).max), csq)
+    csq = csq[:, None, :]                              # (R, 1, Kp)
 
-    np_, dp = xp.shape[-2], xp.shape[-1]
-    kp = cp.shape[-2]
-    grid = (r, np_ // tn, kp // tk)
-
-    if x_batched:
+    dp = xp.shape[-1]
+    if x.ndim == 3:
         x_spec = pl.BlockSpec((1, tn, dp), lambda rr, i, j: (rr, i, 0))
     else:
         x_spec = pl.BlockSpec((tn, dp), lambda rr, i, j: (i, 0))
-    if w_batched:
-        w_spec = pl.BlockSpec((1, tn), lambda rr, i, j: (rr, i))
+    if wp.shape[0] > 1:
+        w_spec = pl.BlockSpec((1, 1, tn), lambda rr, i, j: (rr, 0, i))
     else:
-        w_spec = pl.BlockSpec((tn,), lambda rr, i, j: (i,))
+        w_spec = pl.BlockSpec((1, 1, tn), lambda rr, i, j: (0, 0, i))
+    specs = [x_spec,
+             pl.BlockSpec((1, tk, dp), lambda rr, i, j: (rr, j, 0)),
+             pl.BlockSpec((1, 1, tk), lambda rr, i, j: (rr, 0, j)),
+             w_spec]
+    return [xp, cp, csq, wp], specs
 
+
+def _stat_outputs(r, np_, kp, dp, tn, operands):
+    """(out_specs, out_shape) of the five outputs both kernels emit."""
+    row = pl.BlockSpec((1, 1, tn), lambda rr, i, j: (rr, 0, i))
+    specs = [row, row,
+             pl.BlockSpec((1, kp, dp), lambda rr, i, j: (rr, 0, 0)),
+             pl.BlockSpec((1, 1, kp), lambda rr, i, j: (rr, 0, 0)),
+             pl.BlockSpec((1, 1, 1), lambda rr, i, j: (rr, 0, 0))]
+    shapes = [tiles.out_struct((r, 1, np_), jnp.int32, *operands),
+              tiles.out_struct((r, 1, np_), jnp.float32, *operands),
+              tiles.out_struct((r, kp, dp), jnp.float32, *operands),
+              tiles.out_struct((r, 1, kp), jnp.float32, *operands),
+              tiles.out_struct((r, 1, 1), jnp.float32, *operands)]
+    return specs, shapes
+
+
+def _launch(kind, kernel, operands, in_specs, out_specs, out_shape, *,
+            r, tn, tk, interpret):
+    xp, cp = operands[0], operands[1]
+    np_, dp, kp = xp.shape[-2], xp.shape[-1], cp.shape[-2]
+    if not interpret:
+        tiles.check_tiles(tn, np_, tk, kp)
+    nk = kp // tk
     return pl.pallas_call(
-        functools.partial(_fused_kernel, tk=tk),
-        grid=grid,
-        in_specs=[
-            x_spec,
-            pl.BlockSpec((1, tk, dp), lambda rr, i, j: (rr, j, 0)),
-            pl.BlockSpec((1, tk), lambda rr, i, j: (rr, j)),
-            w_spec,
-        ],
-        out_specs=[
-            pl.BlockSpec((1, tn), lambda rr, i, j: (rr, i)),
-            pl.BlockSpec((1, tn), lambda rr, i, j: (rr, i)),
-            pl.BlockSpec((1, kp, dp), lambda rr, i, j: (rr, 0, 0)),
-            pl.BlockSpec((1, kp), lambda rr, i, j: (rr, 0)),
-            pl.BlockSpec((1, 1), lambda rr, i, j: (rr, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((r, np_), jnp.int32),
-            jax.ShapeDtypeStruct((r, np_), jnp.float32),
-            jax.ShapeDtypeStruct((r, kp, dp), jnp.float32),
-            jax.ShapeDtypeStruct((r, kp), jnp.float32),
-            jax.ShapeDtypeStruct((r, 1), jnp.float32),
-        ],
+        functools.partial(kernel, tk=tk, nk=nk),
+        grid=(r, np_ // tn, nk),
+        in_specs=in_specs,
+        out_specs=out_specs,
+        out_shape=out_shape,
         scratch_shapes=[
             pltpu.VMEM((tn,), jnp.float32),            # running min
             pltpu.VMEM((tn,), jnp.int32),              # running argmin
         ],
         # restarts are independent; stats accumulate across i; the k
         # sweep folds scratch sequentially
-        **tiles.dimension_semantics("parallel", "arbitrary", "arbitrary"),
+        **tiles.compiler_params(
+            kind, ("parallel", "arbitrary", "arbitrary"), tn=tn, tk=tk,
+            kp=kp, dp=dp, itemsize=jnp.dtype(xp.dtype).itemsize,
+            interpret=interpret),
         interpret=interpret,
-    )(xp, cp, csq, wp)
+    )(*operands)
+
+
+@functools.partial(jax.jit, static_argnames=("tn", "tk", "interpret"))
+def _fused_bounds_call(x, cs, w, lab0, lb_sq, ub_sq, *, tn: int, tk: int,
+                       interpret: bool):
+    r = cs.shape[0]
+    operands, in_specs = _operands(x, cs, w, tn=tn, tk=tk)
+    xp, cp = operands[0], operands[1]
+    np_, dp, kp = xp.shape[-2], xp.shape[-1], cp.shape[-2]
+    g = kp // tk
+    assert lb_sq.shape[-1] == g, (lb_sq.shape, g)
+    # padding rows must never force a tile's computation: their lower
+    # bound is +max and their upper bound 0, so lb <= ub is always false
+    fmax = jnp.float32(jnp.finfo(jnp.float32).max)
+    lbp = pad_to(lb_sq, -2, tn, value=fmax)
+    lbp = jnp.swapaxes(lbp, -1, -2)[:, :, None, :]     # (R, G, 1, Np)
+    operands += [lbp, _rows(ub_sq, tn), _rows(lab0, tn)]
+    row = pl.BlockSpec((1, 1, tn), lambda rr, i, j: (rr, 0, i))
+    group = pl.BlockSpec((1, 1, 1, tn), lambda rr, i, j: (rr, j, 0, i))
+    out_specs, out_shape = _stat_outputs(r, np_, kp, dp, tn, operands)
+    out_specs += [group, pl.BlockSpec((1, 1, 1), lambda rr, i, j: (rr, 0, 0))]
+    out_shape += [tiles.out_struct((r, g, 1, np_), jnp.float32, *operands),
+                  tiles.out_struct((r, 1, 1), jnp.float32, *operands)]
+    out = _launch("fused_bounds", _fused_bounds_kernel, operands,
+                  in_specs + [group, row, row], out_specs, out_shape,
+                  r=r, tn=tn, tk=tk, interpret=interpret)
+    gmin = jnp.swapaxes(out[5][:, :, 0, :], -1, -2)    # (R, Np, G)
+    return (*out[:5], gmin, out[6])
+
+
+@functools.partial(jax.jit, static_argnames=("tn", "tk", "interpret"))
+def _fused_call(x, cs, w, *, tn: int, tk: int, interpret: bool):
+    r = cs.shape[0]
+    operands, in_specs = _operands(x, cs, w, tn=tn, tk=tk)
+    xp, cp = operands[0], operands[1]
+    np_, dp, kp = xp.shape[-2], xp.shape[-1], cp.shape[-2]
+    out_specs, out_shape = _stat_outputs(r, np_, kp, dp, tn, operands)
+    return _launch("fused", _fused_kernel, operands, in_specs, out_specs,
+                   out_shape, r=r, tn=tn, tk=tk, interpret=interpret)
 
 
 def fused_lloyd_pallas(x: jax.Array, c: jax.Array, w=None, *,
-                       tn=None, tk=None, interpret: bool = False,
+                       tn=None, tk=None, interpret=None,
                        vmem_bytes=None, bounds=None):
     """Fused assignment+update+energy in ONE physical pass over x.
 
@@ -380,7 +341,10 @@ def fused_lloyd_pallas(x: jax.Array, c: jax.Array, w=None, *,
     energy () f32), each gaining a leading R axis when c is (R, K, d).
 
     Tile sizes default to `tiles.choose_tiles` (VMEM-budget-aware; k is
-    tiled, so arbitrary K takes this path — there is no fallback).
+    tiled, so arbitrary K takes this path — there is no fallback; a
+    shape whose resident accumulator exceeds the chip's VMEM raises).
+    ``interpret`` defaults to `tiles.interpret_default()`: compiled on a
+    TPU, interpreted elsewhere.
 
     ``bounds=(labels0, lb_sq, ub_sq)`` switches to the tile-skipping
     variant (DESIGN.md §Bounds): labels0 (N,) i32 is the standing
@@ -408,6 +372,7 @@ def fused_lloyd_pallas(x: jax.Array, c: jax.Array, w=None, *,
         raise ValueError(
             f"per-problem w {w.shape} needs a per-problem c (R, K, d); "
             f"got {c.shape}")
+    interpret = tiles.resolve_interpret(interpret)
     kind = "fused" if bounds is None else "fused_bounds"
     if tn is None or tk is None:
         ct, ck = tiles.choose_tiles(n, k, d, jnp.dtype(x.dtype).itemsize,
@@ -432,11 +397,12 @@ def fused_lloyd_pallas(x: jax.Array, c: jax.Array, w=None, *,
                                ub_sq.astype(jnp.float32),
                                tn=tn, tk=tk, interpret=interpret)
         n_cells = (gmin.shape[-2] // tn) * g
-        skipped_frac = skipped[:, 0] / jnp.float32(n_cells)
+        skipped_frac = skipped[:, 0, 0] / jnp.float32(n_cells)
         gmin = gmin[:, :n, :]
 
-    labels, mind = labels[:, :n], mind[:, :n]
-    sums, counts, energy = sums[:, :k, :d], counts[:, :k], energy[:, 0]
+    labels, mind = labels[:, 0, :n], mind[:, 0, :n]
+    sums, counts = sums[:, :k, :d], counts[:, 0, :k]
+    energy = energy[:, 0, 0]
     if bounds is not None:
         if not batched:
             return (labels[0], mind[0], sums[0], counts[0], energy[0],

@@ -1,10 +1,8 @@
 """Jit'd dispatch layer over the Pallas kernels.
 
-`use_pallas` resolution:
-  * on TPU backends the compiled kernels run natively;
-  * on CPU (this container) `interpret=True` executes the kernel bodies in
-    Python for correctness validation — the TPU lowering is exercised by the
-    dry-run path.
+`use_pallas=True` runs the kernels, which pick their own execution mode
+(`tiles.interpret_default`): compiled on a TPU, interpreted elsewhere.
+`use_pallas=False` runs the pure-jnp oracles of `kernels/ref.py`.
 
 The solver-facing integration lives in `repro.core.backends`
 (`get_backend("pallas" | "fused")`): the fused single-pass kernel is
@@ -31,19 +29,11 @@ from repro.kernels.fused_lloyd import fused_lloyd_pallas
 from repro.kernels.update import update_pallas
 
 
-def on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
-def _interpret() -> bool:
-    return not on_tpu()
-
-
 def assignment(x: jax.Array, c: jax.Array, *, use_pallas: bool = True):
     """(labels, min_sqdist) — Pallas kernel or jnp oracle.  c may carry a
     leading R axis (R centroid sets in one launch)."""
     if use_pallas:
-        return assignment_pallas(x, c, interpret=_interpret())
+        return assignment_pallas(x, c)
     if c.ndim == 3:
         return jax.vmap(ref.assignment_ref, in_axes=(None, 0))(x, c)
     return ref.assignment_ref(x, c)
@@ -54,7 +44,7 @@ def cluster_update(x: jax.Array, labels: jax.Array, k: int, *,
     """(sums, counts) — Pallas kernel or jnp oracle; optional row
     weights w scale each row's contribution (the minibatch stats)."""
     if use_pallas:
-        return update_pallas(x, labels, k, w=w, interpret=_interpret())
+        return update_pallas(x, labels, k, w=w)
     return ref.update_ref(x, labels, k, w=w)
 
 
@@ -64,7 +54,7 @@ def fused_lloyd_step(x: jax.Array, c: jax.Array, *,
     row weights fold into the stats/energy, and a (R, K, d) centroid
     batch adds a leading R axis to every output."""
     if use_pallas:
-        return fused_lloyd_pallas(x, c, w, interpret=_interpret())
+        return fused_lloyd_pallas(x, c, w)
     if c.ndim == 3:
         fn = (lambda cc: ref.fused_lloyd_ref(x, cc)) if w is None else \
             (lambda cc: ref.minibatch_ref(x, cc, w))

@@ -17,7 +17,8 @@ def assignment_ref(x: jax.Array, c: jax.Array):
     c = c.astype(jnp.float32)
     x_sq = jnp.sum(x * x, axis=-1, keepdims=True)
     c_sq = jnp.sum(c * c, axis=-1)
-    d = jnp.maximum(x_sq - 2.0 * (x @ c.T) + c_sq[None, :], 0.0)
+    cross = jnp.matmul(x, c.T, precision=jax.lax.Precision.HIGHEST)
+    d = jnp.maximum(x_sq - 2.0 * cross + c_sq[None, :], 0.0)
     return jnp.argmin(d, axis=-1).astype(jnp.int32), jnp.min(d, axis=-1)
 
 

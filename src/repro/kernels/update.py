@@ -35,7 +35,7 @@ def _update_kernel(labels_ref, x_ref, w_ref, sums_ref, counts_ref, *,
     labels = labels_ref[...].reshape(-1)               # (TN,)
     x = x_ref[...]
     x = x.reshape(x.shape[-2], x.shape[-1]).astype(jnp.float32)
-    w = w_ref[...]                                     # (TN,) f32
+    w = w_ref[...].reshape(-1)                         # (TN,) f32
 
     local = labels - jk * tk              # position within this tile
     ks = jax.lax.broadcasted_iota(jnp.int32, (labels.shape[0], tk), 1)
@@ -44,8 +44,9 @@ def _update_kernel(labels_ref, x_ref, w_ref, sums_ref, counts_ref, *,
 
     psum = jax.lax.dot_general(
         onehot, x, (((0,), (0,)), ((), ())),
+        precision=tiles.mxu_precision(onehot, x),
         preferred_element_type=jnp.float32)            # (TK, d) on the MXU
-    pcount = jnp.sum(onehot, axis=0)                   # (TK,)
+    pcount = jnp.sum(onehot, axis=0, keepdims=True)    # (1, TK)
 
     @pl.when(i == 0)
     def _init():
@@ -61,15 +62,18 @@ def _update_kernel(labels_ref, x_ref, w_ref, sums_ref, counts_ref, *,
 @functools.partial(jax.jit, static_argnames=("k", "tn", "tk", "interpret"))
 def _update_call(x, labels, w, *, k: int, tn: int, tk: int, interpret: bool):
     r = labels.shape[0]
-    n = x.shape[-2]
     x_batched = x.ndim == 3
 
     xp = pad_to(pad_to(x, -2, tn), -1, tiles.LANE)
-    lp = pad_to(labels.astype(jnp.int32), -1, tn, value=-1)
-    wp = pad_to(w, 0, tn)         # padded rows also weigh 0
+    # lane-major (R, 1, Np) labels and (1, Np) weights; padded rows get
+    # label -1 and weight 0
+    lp = pad_to(labels.astype(jnp.int32), -1, tn, value=-1)[:, None, :]
+    wp = pad_to(w, 0, tn)[None, :]
 
     np_, dp = xp.shape[-2], xp.shape[-1]
     kp = tiles.round_up(k, tk)
+    if not interpret:
+        tiles.check_tiles(tn, np_, tk, kp)
     grid = (r, kp // tk, np_ // tn)
 
     if x_batched:
@@ -81,26 +85,29 @@ def _update_call(x, labels, w, *, k: int, tn: int, tk: int, interpret: bool):
         functools.partial(_update_kernel, tk=tk),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, tn), lambda rr, jk, i: (rr, i)),
+            pl.BlockSpec((1, 1, tn), lambda rr, jk, i: (rr, 0, i)),
             x_spec,
-            pl.BlockSpec((tn,), lambda rr, jk, i: (i,)),
+            pl.BlockSpec((1, tn), lambda rr, jk, i: (0, i)),
         ],
         out_specs=[
             pl.BlockSpec((1, tk, dp), lambda rr, jk, i: (rr, jk, 0)),
-            pl.BlockSpec((1, tk), lambda rr, jk, i: (rr, jk)),
+            pl.BlockSpec((1, 1, tk), lambda rr, jk, i: (rr, 0, jk)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((r, kp, dp), jnp.float32),
-            jax.ShapeDtypeStruct((r, kp), jnp.float32),
+            tiles.out_struct((r, kp, dp), jnp.float32, lp, xp, wp),
+            tiles.out_struct((r, 1, kp), jnp.float32, lp, xp, wp),
         ],
-        **tiles.dimension_semantics("parallel", "parallel", "arbitrary"),
+        **tiles.compiler_params(
+            "update", ("parallel", "parallel", "arbitrary"), tn=tn, tk=tk,
+            kp=kp, dp=dp, itemsize=jnp.dtype(xp.dtype).itemsize,
+            interpret=interpret),
         interpret=interpret,
     )(lp, xp, wp)
-    return sums[:, :k, :x.shape[-1]], counts[:, :k]
+    return sums[:, :k, :x.shape[-1]], counts[:, 0, :k]
 
 
 def update_pallas(x: jax.Array, labels: jax.Array, k: int, *,
-                  w=None, tn=None, tk=None, interpret: bool = False,
+                  w=None, tn=None, tk=None, interpret=None,
                   vmem_bytes=None):
     """Per-cluster sums (K,d) f32 and counts (K,) f32 via the Pallas kernel.
 
@@ -109,6 +116,7 @@ def update_pallas(x: jax.Array, labels: jax.Array, k: int, *,
     w: optional (N,) row weights scaling each row's contribution (the
     weighted segment-sum of the minibatch step).  Tile-padded sample rows
     get label -1 *and* weight 0, so they land in no cluster.
+    ``interpret`` defaults to `tiles.interpret_default()`.
     """
     batched = labels.ndim == 2
     if x.ndim == 3 and not batched:
@@ -127,7 +135,7 @@ def update_pallas(x: jax.Array, labels: jax.Array, k: int, *,
         tn = ct if tn is None else tn
         tk = ck if tk is None else tk
     sums, counts = _update_call(x, ls, w, k=k, tn=tn, tk=tk,
-                                interpret=interpret)
+                                interpret=tiles.resolve_interpret(interpret))
     if not batched:
         return sums[0], counts[0]
     return sums, counts

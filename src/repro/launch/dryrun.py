@@ -26,7 +26,6 @@ from pathlib import Path
 
 import jax
 
-from repro import compat
 from repro.configs.registry import ARCHS, get_config
 from repro.launch import steps as ST
 from repro.launch.mesh import make_production_mesh
@@ -185,7 +184,7 @@ _CAL_METRICS = ("flops", "bytes", "dot_flops")
 
 
 def _collect_costs(compiled):
-    ca = compat.cost_analysis(compiled)
+    ca = compiled.cost_analysis()
     hlo = compiled.as_text()
     _, wire, _ = parse_collectives(hlo)
     return {"flops": float(ca.get("flops", 0.0)),
@@ -269,7 +268,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str,
         compiled = lowered.compile()
         rec["time_compile_s"] = round(time.perf_counter() - t1, 2)
 
-        ca = compat.cost_analysis(compiled)
+        ca = compiled.cost_analysis()
         rec["hlo_flops_per_device"] = float(ca.get("flops", 0.0))
         rec["hlo_bytes_per_device"] = float(ca.get("bytes accessed", 0.0))
         rec["memory"] = memory_dict(compiled)

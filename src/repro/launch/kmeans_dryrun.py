@@ -32,7 +32,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.core import anderson, lloyd
 from repro.core.anderson import AAConfig
 from repro.launch.dryrun import (ARTIFACTS, memory_dict, parse_collectives,
@@ -152,7 +151,7 @@ def run_variant(mesh_kind: str, variant: str, save=True):
         t1 = time.perf_counter()
         compiled = lowered.compile()
         rec["time_compile_s"] = round(time.perf_counter() - t1, 2)
-        ca = compat.cost_analysis(compiled)
+        ca = compiled.cost_analysis()
         rec["hlo_flops_per_device"] = float(ca.get("flops", 0.0))
         rec["hlo_bytes_per_device"] = float(ca.get("bytes accessed", 0.0))
         rec["memory"] = memory_dict(compiled)

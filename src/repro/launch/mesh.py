@@ -13,14 +13,14 @@ from __future__ import annotations
 
 import jax
 
-from repro.compat import AxisType, make_mesh
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes,
-                     axis_types=(AxisType.Auto,) * len(axes))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(shape=None, axes=None):
@@ -28,8 +28,8 @@ def make_host_mesh(shape=None, axes=None):
     n = len(jax.devices())
     if shape is None:
         shape, axes = (n,), ("data",)
-    return make_mesh(shape, axes,
-                     axis_types=(AxisType.Auto,) * len(axes))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def data_axes_of(mesh) -> tuple:

@@ -211,7 +211,8 @@ def _routed_sqdist(x, g, table, n_valid=None):
     cc = table[g]                                  # (N, C, d) block rows
     x_sq = jnp.sum(x * x, axis=-1, keepdims=True)               # (N, 1)
     c_sq = jnp.sum(table * table, axis=-1)[g]                   # (N, C)
-    cross = jnp.einsum("nd,ncd->nc", x, cc)                     # (N, C)
+    cross = jnp.einsum("nd,ncd->nc", x, cc,
+                       precision=lloyd.MATMUL_PRECISION)        # (N, C)
     d2 = jnp.maximum(x_sq - 2.0 * cross + c_sq, 0.0)
     if n_valid is None:
         return d2

@@ -22,8 +22,6 @@ from typing import Dict, Optional, Sequence, Tuple, Union
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro import compat  # noqa: F401  (jax version shims: AxisType et al.)
-
 Axis = Union[None, str, Tuple[str, ...]]
 
 

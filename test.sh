@@ -13,13 +13,11 @@
 #                          #   streaming smoke, and the perf smokes
 #                          #   (kernels_bench/checkpoint_bench --smoke,
 #                          #   emitting BENCH_*.json)
-#   ./test.sh --interpret  # interpret tier: the kernel-facing suites
+#   ./test.sh --kernels    # kernel tier: the kernel-facing suites
 #                          #   (kernels v1/v2, conformance, bounds,
-#                          #   locality) with
-#                          #   REPRO_PALLAS_INTERPRET=1, forcing every
-#                          #   pallas_call through interpret mode even
-#                          #   where a compiled path would be picked —
-#                          #   the off-TPU check of the kernel sources
+#                          #   locality, hierarchy) in interpret mode,
+#                          #   plus the Mosaic compile rehearsals for a
+#                          #   described v5e (tests/test_tpu_compile.py)
 #   ./test.sh -m 'conformance'   # any extra pytest args pass through
 #   ./test.sh -m 'perf'          # just the benchmark-harness smokes
 #   ./test.sh tests/test_persistence.py   # just the persistence suite
@@ -35,16 +33,18 @@ cd "$(dirname "$0")"
 export PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}"
 export XLA_FLAGS="${XLA_FLAGS:---xla_force_host_platform_device_count=8}"
 # Containers with libtpu installed stall for minutes probing GCP instance
-# metadata unless the platform is pinned; override for real-TPU runs.
+# metadata unless the platform is pinned.  The tests run on the CPU, the
+# Pallas kernels in interpret mode; the chip is exercised by
+# chip_smoke.py (README.md).
 export JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}"
 
-if [[ "${1:-}" == "--interpret" ]]; then
+if [[ "${1:-}" == "--kernels" ]]; then
     shift
-    export REPRO_PALLAS_INTERPRET=1
     exec python -m pytest -x -q -m 'not slow' \
         tests/test_kernels.py tests/test_kernels_v2.py \
         tests/test_conformance.py tests/test_bounds.py \
-        tests/test_locality.py tests/test_hierarchy.py "$@"
+        tests/test_locality.py tests/test_hierarchy.py \
+        tests/test_tpu_compile.py "$@"
 fi
 if [[ "${1:-}" == "--slow" ]]; then
     shift

@@ -60,7 +60,6 @@ def test_estimator_predict_uses_fitted_mesh():
     the multi-device behaviour rides the same shard_map contract as
     fit (tests/test_distributed.py)."""
     import jax
-    from repro import compat
 
     mesh = jax.make_mesh((1,), ("data",),
                          axis_types=(jax.sharding.AxisType.Auto,))

@@ -19,8 +19,8 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.core import backends as B
+from repro.core.distributed import shard_map
 from repro.core.init_schemes import kmeanspp_init
 from repro.core.kmeans import KMeansConfig, aa_kmeans, aa_kmeans_traced
 from repro.data.synthetic import make_blobs
@@ -125,10 +125,10 @@ def test_distribute_combinator_single_device(name, fixture):
         res, _ = dist.step(xx, cc, K, dist.init_carry(xx, cc, K))
         return res
 
-    res = compat.shard_map(run, mesh=mesh, in_specs=(P("data"), P()),
-                           out_specs=B.StepResult(
-                               labels=P("data"), min_sqdist=P("data"),
-                               sums=P(), counts=P(), energy=P()))(x, c)
+    res = shard_map(run, mesh=mesh, in_specs=(P("data"), P()),
+                    out_specs=B.StepResult(
+                        labels=P("data"), min_sqdist=P("data"),
+                        sums=P(), counts=P(), energy=P()))(x, c)
     ref = _step(backend, x, c)
     assert (np.asarray(res.labels) == np.asarray(ref.labels)).all()
     np.testing.assert_allclose(res.sums, ref.sums, rtol=0, atol=0)
@@ -152,9 +152,9 @@ def test_distributed_energy_op_reduces_once():
     dist = B.distribute(dense, ("data",))
     mesh = jax.make_mesh((n_dev,), ("data",),
                          axis_types=(jax.sharding.AxisType.Auto,))
-    e = compat.shard_map(lambda xx, cc, ll: dist.energy(xx, cc, ll),
-                         mesh=mesh, in_specs=(P("data"), P(), P("data")),
-                         out_specs=P())(x, c, labels)
+    e = jax.shard_map(lambda xx, cc, ll: dist.energy(xx, cc, ll),
+                      mesh=mesh, in_specs=(P("data"), P(), P("data")),
+                      out_specs=P())(x, c, labels)
     np.testing.assert_allclose(float(e), e_ref, rtol=1e-5)
 
 

@@ -131,8 +131,9 @@ def test_update_weighted_and_batched_parity():
 
 def test_k_straddling_old_gate_stays_fused(monkeypatch):
     """A K*d block bigger than the (monkeypatched) budget k-tiles via the
-    chooser and stays correct — v1 would have refused this shape."""
-    n, d, k = 120, 6, 40
+    chooser and stays correct — v1 would have refused this shape.  K
+    exceeds one 128-lane tile so the chooser can split it lane-legally."""
+    n, d, k = 120, 6, 300
     x, c = _mk(n, d, k, seed=9)
     monkeypatch.setattr(tiles, "DEFAULT_VMEM_BUDGET", k * d * 4 - 1)
     tn, tk = tiles.choose_tiles(n, k, d, 4, kind="fused")
@@ -151,13 +152,14 @@ def test_tile_chooser_fits_budget_and_floors():
     for kind in ("fused", "assignment", "update"):
         tn, tk = tiles.choose_tiles(100_000, 1000, 64, 4, kind=kind,
                                     vmem_bytes=budget)
-        assert tn % 8 == 0 and tk % 8 == 0
+        # tiles that split an axis are lane-legal multiples of 128
+        assert tn % tiles.LANE == 0 and tk % tiles.LANE == 0
         kp = tiles.round_up(1000, tk)
         # tile-dependent cost fits what the (resident-capped) budget
         # leaves; the fused accumulator may irreducibly exceed its half
         charged = min(tiles._resident(kind, kp, 128), budget // 2)
         assert tiles._tile_cost(kind, tn, tk, 128, 4) + charged <= budget \
-            or (tn == 8 and tk == 8)
+            or (tn == tiles.LANE and tk == tiles.LANE)
     # ample budget: full 512 tiles
     assert tiles.choose_tiles(100_000, 1000, 8, 4, kind="assignment",
                               vmem_bytes=64 << 20) == (512, 512)

@@ -73,14 +73,13 @@ def test_distributed_minibatch_step_psums_once(problem):
     the local step exactly (psum = identity); the multi-device version
     lives in test_distributed."""
     from jax.sharding import PartitionSpec as P
-    from repro import compat
     x, _, _, c = (*problem[:3], problem[3])
     xc, w = x[:1024], jnp.ones(1024)
     dense = B.get_backend("dense")
     dist = B.distribute(dense, ("data",))
     mesh = jax.make_mesh((1,), ("data",),
                          axis_types=(jax.sharding.AxisType.Auto,))
-    res = compat.shard_map(
+    res = jax.shard_map(
         lambda a, b, ww: dist.minibatch_step(a, b, K, ww, ())[0],
         mesh=mesh, in_specs=(P("data"), P(), P("data")),
         out_specs=B.StepResult(labels=P("data"), min_sqdist=P("data"),

@@ -434,3 +434,28 @@ def test_driver_metrics_emission(tmp_path):
     ref = aa_kmeans(x, c0, cfg)
     assert mx2.records
     assert float(res.energy) == float(ref.energy)
+
+
+# -- persistent compilation cache placement --------------------------------
+
+@pytest.mark.parametrize("env_set", [True, False], ids=["env", "repo"])
+def test_compile_cache_dir_placed_from_outside(env_set, tmp_path,
+                                               monkeypatch):
+    """A set JAX_COMPILATION_CACHE_DIR is the cache directory; unset, the
+    cache lives at the fixed <repo>/.jax_cache."""
+    from pathlib import Path
+    from repro.runtime import compile_cache
+    repo = Path(__file__).resolve().parents[1]
+    if env_set:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        want = str(tmp_path)
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        want = str(repo / ".jax_cache")
+    assert compile_cache.cache_dir() == want
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        assert compile_cache.enable_compile_cache() == want
+        assert jax.config.jax_compilation_cache_dir == want
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
