@@ -103,6 +103,7 @@ def aa_seed(state: AAState, f0: jax.Array, g0: jax.Array) -> AAState:
     return state._replace(f_prev=f0, g_prev=g0)
 
 
+@jax.named_scope("repro.aa")
 def adjust_m(state: AAState, e_curr: jax.Array, e_prev: jax.Array,
              e_prev2: jax.Array, cfg: AAConfig) -> AAState:
     """Algorithm 1 lines 7-11.  Guarded for t < 2 (e_prev2 = +inf) and for a
@@ -154,6 +155,7 @@ def _column_ages(state: AAState, mbar: int) -> jax.Array:
     return jnp.where(age <= state.ncols, age, mbar + 1)
 
 
+@jax.named_scope("repro.aa")
 def aa_push_and_solve(state: AAState, f: jax.Array, g: jax.Array,
                       cfg: AAConfig):
     """Push (F^t, G^t), solve (7) over the active window, return C^{t+1}.

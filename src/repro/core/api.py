@@ -108,12 +108,19 @@ def _chunked_rows_apply(model, x, kind, fn, out_dtype, out_cols=None,
     shape = (n,) if out_cols is None else (n, out_cols)
     out = np.empty(shape, out_dtype)
     for i in range(0, n, step):
-        xc = jnp.asarray(x[i:i + step])
-        m = xc.shape[0]
-        if m < step:
-            xc = jnp.concatenate(
-                [xc, jnp.repeat(xc[-1:], step - m, axis=0)])
-        out[i:i + m] = np.asarray(run(xc, c, *extras))[:m]
+        # put stages the chunk on the host; the runtime relayouts and
+        # copies it to the device after the span closes, so that wait
+        # falls inside fetch, with the program's run and the copy back
+        with jax.profiler.TraceAnnotation("repro.rows.put"):
+            xc = jnp.asarray(x[i:i + step])
+            m = xc.shape[0]
+            if m < step:
+                xc = jnp.concatenate(
+                    [xc, jnp.repeat(xc[-1:], step - m, axis=0)])
+        with jax.profiler.TraceAnnotation("repro.rows.run"):
+            yc = run(xc, c, *extras)
+        with jax.profiler.TraceAnnotation("repro.rows.fetch"):
+            out[i:i + m] = np.asarray(yc)[:m]
     return out
 
 
@@ -373,27 +380,33 @@ class AAKMeans:
         n_init = max(self.n_init, 1)
         if self.hierarchical:
             return self._fit_hierarchical(x, cfg, n_init)
-        keys = jax.random.split(jax.random.PRNGKey(self.seed), n_init)
-        c0s = jnp.asarray(batched_init(self.init, keys, x, self.n_clusters))
-        if self.mesh is not None:
-            fit_fn = make_distributed_kmeans_batched(
-                self.mesh, cfg, self.data_axes, backend=self.backend,
-                pick_best=True)
-            x_in, _ = shard_dataset(x, self.mesh, self.data_axes)
-        elif self.metrics is not None:
-            # segmented (host-loop) driver: metrics need host boundaries
-            fit_fn = lambda a, b: select_best(  # noqa: E731
-                aa_kmeans_batched(a, b, cfg, backend=self.backend,
-                                  metrics=self.metrics))
-            x_in = x
-        else:
-            fit_fn = jax.jit(lambda a, b: select_best(
-                aa_kmeans_batched(a, b, cfg, backend=self.backend)))
-            x_in = x
-        # ONE device program: R restarts solved in a batch, winner picked
-        # on device — n_init no longer multiplies dispatch/transfer cost.
-        best: KMeansResult = fit_fn(x_in, c0s)
-        energy = float(best.energy)
+        with jax.profiler.TraceAnnotation("repro.fit.seed"):
+            keys = jax.random.split(jax.random.PRNGKey(self.seed), n_init)
+            c0s = jnp.asarray(batched_init(self.init, keys, x,
+                                           self.n_clusters))
+        with jax.profiler.TraceAnnotation("repro.fit.solve"):
+            if self.mesh is not None:
+                fit_fn = make_distributed_kmeans_batched(
+                    self.mesh, cfg, self.data_axes, backend=self.backend,
+                    pick_best=True)
+                x_in, _ = shard_dataset(x, self.mesh, self.data_axes)
+            elif self.metrics is not None:
+                # segmented (host-loop) driver: metrics need host boundaries
+                fit_fn = lambda a, b: select_best(  # noqa: E731
+                    aa_kmeans_batched(a, b, cfg, backend=self.backend,
+                                      metrics=self.metrics))
+                x_in = x
+            else:
+                fit_fn = jax.jit(lambda a, b: select_best(
+                    aa_kmeans_batched(a, b, cfg, backend=self.backend)))
+                x_in = x
+            # ONE device program: R restarts solved in a batch, winner
+            # picked on device — n_init no longer multiplies
+            # dispatch/transfer cost.
+            best: KMeansResult = fit_fn(x_in, c0s)
+        with jax.profiler.TraceAnnotation("repro.fit.result"):
+            energy = float(best.energy)
+            n_iter, n_accepted = int(best.n_iter), int(best.n_accepted)
         if not math.isfinite(energy):
             # select_best skips non-finite restarts, so reaching here means
             # EVERY restart degenerated (NaN rows in X, exploded iterate).
@@ -405,8 +418,8 @@ class AAKMeans:
         self.centroids_ = best.centroids
         self.labels_ = best.labels[:n]
         self.energy_ = energy
-        self.n_iter_ = int(best.n_iter)
-        self.n_accepted_ = int(best.n_accepted)
+        self.n_iter_ = n_iter
+        self.n_accepted_ = n_accepted
         # fresh centroids invalidate any previous closure index (and any
         # previous hierarchical structure); rebuild when requested, never
         # serve a stale one

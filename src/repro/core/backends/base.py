@@ -179,9 +179,11 @@ class Backend:
 
     # -- core op ----------------------------------------------------------
 
+    @jax.named_scope("repro.step")
     def step(self, x, c, k, carry=()):
         return self.step_fn(x, c, k, carry)
 
+    @jax.named_scope("repro.step")
     def batched_step(self, x, cs, k, carries, x_batched: bool = False,
                      w=None):
         """R restarts' steps at once; falls back to vmapping ``step``.
@@ -201,6 +203,7 @@ class Backend:
             lambda xx, cc, ww, cr: self.minibatch_step(xx, cc, k, ww, cr),
             in_axes=(xa, 0, 0, 0))(x, cs, w, carries)
 
+    @jax.named_scope("repro.step")
     def minibatch_step(self, x, c, k, w, carry=()):
         """Weighted single pass over a chunk (DESIGN.md §Streaming).
 
