@@ -24,6 +24,7 @@ mesh-fitted model keeps using its mesh for predict/transform.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 import re
 from typing import Optional
@@ -48,6 +49,30 @@ from repro.core.minibatch import (MiniBatchConfig, MiniBatchState,
                                   minibatch_iteration)
 from repro.data.streaming import (chunk_dataset, shard_count,
                                   split_validation)
+
+
+_FIT_PROGRAM_TRACES = 0
+
+
+@functools.partial(jax.jit, static_argnames=("cfg", "backend"))
+def _fit_program(x, c0s, cfg: KMeansConfig, backend) -> KMeansResult:
+    """`AAKMeans.fit`'s single-device program: the batched solve over the
+    (R, K, d) seeds and the best restart picked on device.  One module-
+    level jit, so a configuration (``cfg``, ``backend``, the shapes of
+    ``x`` and ``c0s``) is traced and lowered once per process, not once
+    per fit.  ``backend`` stays unresolved in the static key: a registry
+    name or the user's Backend instance compares equal across fits, where
+    an adapter built per call would not."""
+    global _FIT_PROGRAM_TRACES
+    _FIT_PROGRAM_TRACES += 1            # the body runs at trace time only
+    return select_best(aa_kmeans_batched(x, c0s, cfg, backend=backend))
+
+
+def fit_program_traces() -> int:
+    """How many times `AAKMeans.fit`'s single-device program has been
+    traced in this process; over the number of such fits, the share of
+    fits that missed the jit cache."""
+    return _FIT_PROGRAM_TRACES
 
 
 class NotFittedError(RuntimeError):
@@ -397,8 +422,8 @@ class AAKMeans:
                                       metrics=self.metrics))
                 x_in = x
             else:
-                fit_fn = jax.jit(lambda a, b: select_best(
-                    aa_kmeans_batched(a, b, cfg, backend=self.backend)))
+                fit_fn = functools.partial(_fit_program, cfg=cfg,
+                                           backend=self.backend)
                 x_in = x
             # ONE device program: R restarts solved in a batch, winner
             # picked on device — n_init no longer multiplies

@@ -107,6 +107,82 @@ def test_chunked_runner_traces_once_across_remainders():
         f"expected ONE trace at the padded chunk shape; got {traced_shapes}"
 
 
+def _assert_matches_per_call_jit(model, x):
+    """The fitted model equals, bit for bit, a fresh per-call ``jax.jit``
+    of the same solve from the same seeds."""
+    import jax
+    import jax.numpy as jnp
+    from repro.core.init_schemes import batched_init
+    from repro.core.kmeans import aa_kmeans_batched, select_best
+
+    x = jnp.asarray(x)
+    cfg = model._config()
+    keys = jax.random.split(jax.random.PRNGKey(model.seed),
+                            max(model.n_init, 1))
+    c0s = jnp.asarray(batched_init(model.init, keys, x, model.n_clusters))
+    best = jax.jit(lambda a, b: select_best(aa_kmeans_batched(
+        a, b, cfg, backend=model.backend)))(x, c0s)
+    np.testing.assert_array_equal(np.asarray(model.centroids_),
+                                  np.asarray(best.centroids))
+    np.testing.assert_array_equal(np.asarray(model.labels_),
+                                  np.asarray(best.labels[:x.shape[0]]))
+    assert model.n_iter_ == int(best.n_iter)
+    assert model.n_accepted_ == int(best.n_accepted)
+
+
+def _traces_of_fit(model, x):
+    from repro.core.api import fit_program_traces
+    before = fit_program_traces()
+    model.fit(x)
+    return fit_program_traces() - before
+
+
+def test_fit_program_traces_once_across_refits():
+    """Fresh estimators with the same parameters on data of one shape
+    share one traced fit program (the per-fit ``jax.jit`` rebuilt it on
+    every fit), and each answer is the per-call jit's, bit for bit."""
+    x = make_blobs(613, 5, 4, seed=11, spread=3.0)
+    traces = []
+    for s in (0, 1, 2):
+        m = AAKMeans(n_clusters=4, seed=s)
+        traces.append(_traces_of_fit(m, x))
+        _assert_matches_per_call_jit(m, x)
+    assert sum(traces) == 1 and traces[1:] == [0, 0], traces
+
+
+@pytest.mark.parametrize("change", [{"max_iter": 37}, {"n_clusters": 3},
+                                    {"n_init": 2}])
+def test_fit_program_retraces_per_configuration(change):
+    """A parameter that shapes the program (a static config field, K, or
+    the number of restarts) gets a program of its own, traced once."""
+    x = make_blobs(617, 5, 4, seed=12, spread=3.0)
+    AAKMeans(n_clusters=4, seed=0).fit(x)
+    params = {"n_clusters": 4, "seed": 0, **change}
+    m = AAKMeans(**params)
+    assert _traces_of_fit(m, x) == 1
+    _assert_matches_per_call_jit(m, x)
+    assert _traces_of_fit(AAKMeans(**{**params, "seed": 1}), x) == 0
+
+
+def test_fit_program_backend_instance_traces_once():
+    """A Backend instance built once and handed to two estimators keys one
+    program; a different backend on the same data never shares it."""
+    from repro.core.backends import get_backend
+
+    x = make_blobs(619, 5, 4, seed=13, spread=3.0)
+    blocked = get_backend("blocked", block_n=128)
+    m0 = AAKMeans(n_clusters=4, seed=0, backend=blocked)
+    m1 = AAKMeans(n_clusters=4, seed=1, backend=blocked)
+    assert _traces_of_fit(m0, x) == 1
+    assert _traces_of_fit(m1, x) == 0
+    for m in (m0, m1):
+        _assert_matches_per_call_jit(m, x)
+    for other in ("dense", "hamerly"):
+        m = AAKMeans(n_clusters=4, seed=0, backend=other)
+        assert _traces_of_fit(m, x) == 1, other
+        _assert_matches_per_call_jit(m, x)
+
+
 def test_unfitted_inference_raises_not_fitted_error():
     from repro.core.api import MiniBatchAAKMeans, NotFittedError
     q = np.zeros((4, 3), np.float32)
