@@ -38,10 +38,11 @@ from repro.core import serialize
 from repro.core.anderson import AAConfig
 from repro.core.distributed import (make_distributed_kmeans_batched,
                                     make_distributed_kmeans_minibatch,
-                                    shard_dataset, shard_map)
+                                    row_take, shard_dataset, shard_map)
 from repro.core.init_schemes import batched_init, make_init
 from repro.core.kmeans import (KMeansConfig, KMeansResult,
-                               aa_kmeans_batched, aa_kmeans_minibatch,
+                               aa_kmeans_batched, aa_kmeans_batched_close,
+                               aa_kmeans_batched_loop, aa_kmeans_minibatch,
                                minibatch_stream_like, resolve_backend,
                                select_best)
 from repro.core.minibatch import (MiniBatchConfig, MiniBatchState,
@@ -55,17 +56,28 @@ _FIT_PROGRAM_TRACES = 0
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "backend"))
-def _fit_program(x, c0s, cfg: KMeansConfig, backend) -> KMeansResult:
-    """`AAKMeans.fit`'s single-device program: the batched solve over the
-    (R, K, d) seeds and the best restart picked on device.  One module-
-    level jit, so a configuration (``cfg``, ``backend``, the shapes of
-    ``x`` and ``c0s``) is traced and lowered once per process, not once
-    per fit.  ``backend`` stays unresolved in the static key: a registry
-    name or the user's Backend instance compares equal across fits, where
-    an adapter built per call would not."""
+def _fit_program(x, c0s, cfg: KMeansConfig, backend):
+    """`AAKMeans.fit`'s single-device solve: the batched loop over the
+    (R, K, d) seeds.  One module-level jit, so a configuration (``cfg``,
+    ``backend``, the shapes of ``x`` and ``c0s``) is traced and lowered
+    once per process, not once per fit.  ``backend`` stays unresolved in
+    the static key: a registry name or the user's Backend instance
+    compares equal across fits, where an adapter built per call would
+    not."""
     global _FIT_PROGRAM_TRACES
     _FIT_PROGRAM_TRACES += 1            # the body runs at trace time only
-    return select_best(aa_kmeans_batched(x, c0s, cfg, backend=backend))
+    return aa_kmeans_batched_loop(x, c0s, cfg, backend)
+
+
+@functools.partial(jax.jit, static_argnames=("cfg", "backend"))
+def _close_program(x, states, cfg: KMeansConfig, backend) -> KMeansResult:
+    """The rest of the single-device fit: the closing evaluation of the
+    restarts that hit ``max_iter`` and the best restart picked on
+    device.  A program of its own, dispatched behind `_fit_program`
+    with no host sync between, so that its buffers play no part in how
+    XLA lays out the solve's loop: a converged fit runs the very loop it
+    ran before capped fits were closed."""
+    return select_best(aa_kmeans_batched_close(x, states, cfg, backend))
 
 
 def fit_program_traces() -> int:
@@ -73,6 +85,33 @@ def fit_program_traces() -> int:
     traced in this process; over the number of such fits, the share of
     fits that missed the jit cache."""
     return _FIT_PROGRAM_TRACES
+
+
+_MESH_FIT_PROGRAM_TRACES = 0
+
+
+def _mesh_fit_program(mesh, cfg: KMeansConfig, data_axes, backend):
+    """`AAKMeans.fit`'s program on a mesh: the batched solve with rows
+    sharded over ``data_axes`` and the best restart picked on device
+    (`make_distributed_kmeans_batched`).  Built anew on every fit, so
+    every fit traces and lowers it again; `mesh_fit_program_traces`
+    counts those traces."""
+    solve = make_distributed_kmeans_batched(mesh, cfg, data_axes,
+                                            backend=backend, pick_best=True)
+
+    @jax.jit
+    def program(x, c0s):
+        global _MESH_FIT_PROGRAM_TRACES
+        _MESH_FIT_PROGRAM_TRACES += 1   # the body runs at trace time only
+        return solve(x, c0s)
+
+    return program
+
+
+def mesh_fit_program_traces() -> int:
+    """How many times `AAKMeans.fit`'s mesh program has been traced in
+    this process; over the number of mesh fits, the traces per fit."""
+    return _MESH_FIT_PROGRAM_TRACES
 
 
 class NotFittedError(RuntimeError):
@@ -399,36 +438,43 @@ class AAKMeans:
                         eps1=self.eps1, eps2=self.eps2, ridge=self.ridge))
 
     def fit(self, x) -> "AAKMeans":
-        x = jnp.asarray(x)
-        n = x.shape[0]
+        """Fit on ``x`` (N, d).  With a mesh, ``x`` may be rows already
+        sharded over ``data_axes``: seeding, the solve and ``labels_``
+        then stay on the shards, and X is never gathered; host rows go
+        straight to their shards."""
         cfg = self._config()
         n_init = max(self.n_init, 1)
-        if self.hierarchical:
-            return self._fit_hierarchical(x, cfg, n_init)
+        take, pad, real = None, 0, None
+        if self.mesh is None or self.hierarchical:
+            x = jnp.asarray(x)
+            if self.hierarchical:
+                return self._fit_hierarchical(x, cfg, n_init)
+        else:
+            x, pad = shard_dataset(x, self.mesh, self.data_axes)
+            take = row_take(self.mesh, tuple(self.data_axes))
+        n = x.shape[0] - pad
+        if pad:     # padding rows weigh 0, so seeding never picks one
+            real = jnp.broadcast_to(jnp.arange(n + pad) < n,
+                                    (n_init, n + pad)).astype(x.dtype)
         with jax.profiler.TraceAnnotation("repro.fit.seed"):
             keys = jax.random.split(jax.random.PRNGKey(self.seed), n_init)
             c0s = jnp.asarray(batched_init(self.init, keys, x,
-                                           self.n_clusters))
+                                           self.n_clusters, weights=real,
+                                           take=take))
+        # R restarts solved in a batch, winner picked on device — n_init
+        # does not multiply dispatch/transfer cost.
         with jax.profiler.TraceAnnotation("repro.fit.solve"):
             if self.mesh is not None:
-                fit_fn = make_distributed_kmeans_batched(
-                    self.mesh, cfg, self.data_axes, backend=self.backend,
-                    pick_best=True)
-                x_in, _ = shard_dataset(x, self.mesh, self.data_axes)
+                best = _mesh_fit_program(self.mesh, cfg, self.data_axes,
+                                         self.backend)(x, c0s)
             elif self.metrics is not None:
                 # segmented (host-loop) driver: metrics need host boundaries
-                fit_fn = lambda a, b: select_best(  # noqa: E731
-                    aa_kmeans_batched(a, b, cfg, backend=self.backend,
-                                      metrics=self.metrics))
-                x_in = x
+                best = select_best(aa_kmeans_batched(
+                    x, c0s, cfg, backend=self.backend, metrics=self.metrics))
             else:
-                fit_fn = functools.partial(_fit_program, cfg=cfg,
-                                           backend=self.backend)
-                x_in = x
-            # ONE device program: R restarts solved in a batch, winner
-            # picked on device — n_init no longer multiplies
-            # dispatch/transfer cost.
-            best: KMeansResult = fit_fn(x_in, c0s)
+                states = _fit_program(x, c0s, cfg=cfg, backend=self.backend)
+                best = _close_program(x, states, cfg=cfg,
+                                      backend=self.backend)
         with jax.profiler.TraceAnnotation("repro.fit.result"):
             energy = float(best.energy)
             n_iter, n_accepted = int(best.n_iter), int(best.n_accepted)
@@ -441,7 +487,7 @@ class AAKMeans:
                 f"all {n_init} restarts produced non-finite energies "
                 f"(E={energy}); check X for NaN/inf rows")
         self.centroids_ = best.centroids
-        self.labels_ = best.labels[:n]
+        self.labels_ = best.labels[:n] if pad else best.labels
         self.energy_ = energy
         self.n_iter_ = n_iter
         self.n_accepted_ = n_accepted

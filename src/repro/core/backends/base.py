@@ -312,6 +312,12 @@ def distribute(backend: Backend, axes: Sequence[str]) -> Backend:
             f"stats and inflate the reported energy")
     axes = tuple(axes)
 
+    # Every collective of the distributed solver runs under one device
+    # scope, so a trace names its all-reduces apart from the local work.
+    def psum(a):
+        with jax.named_scope("repro.collective"):
+            return jax.lax.psum(a, axes)
+
     def reduce_carry(carry):
         """Per-row bounds stay shard-local, but the BoundStats scalars a
         bound backend reports are per-shard fractions — pmean them so
@@ -324,21 +330,23 @@ def distribute(backend: Backend, axes: Sequence[str]) -> Backend:
 
         def fix(node):
             if isinstance(node, BoundStats):
-                return jax.tree_util.tree_map(
-                    lambda a: jax.lax.pmean(a, axes), node)
+                with jax.named_scope("repro.collective"):
+                    return jax.tree_util.tree_map(
+                        lambda a: jax.lax.pmean(a, axes), node)
             return node
 
         return jax.tree_util.tree_map(
             fix, carry, is_leaf=lambda n: isinstance(n, BoundStats))
 
+    def reduce_step(res: StepResult) -> StepResult:
+        """The (K,(d+1))-sized stats and the energy, summed over shards;
+        labels and min_sqdist stay shard-local."""
+        return res._replace(sums=psum(res.sums), counts=psum(res.counts),
+                            energy=psum(res.energy))
+
     def step_fn(x, c, k, carry):
         res, carry = backend.step_fn(x, c, k, carry)
-        return StepResult(
-            labels=res.labels,
-            min_sqdist=res.min_sqdist,
-            sums=jax.lax.psum(res.sums, axes),
-            counts=jax.lax.psum(res.counts, axes),
-            energy=jax.lax.psum(res.energy, axes)), reduce_carry(carry)
+        return reduce_step(res), reduce_carry(carry)
 
     # The local batched step (when present) must be re-wrapped so its
     # (R, K, d+1)-stats psum too — one collective covers all R restarts.
@@ -348,12 +356,7 @@ def distribute(backend: Backend, axes: Sequence[str]) -> Backend:
     if backend.batched_step_fn is not None:
         def batched_step_fn(x, cs, k, carries, w=None):
             res, carries = backend.batched_step_fn(x, cs, k, carries, w=w)
-            return StepResult(
-                labels=res.labels,
-                min_sqdist=res.min_sqdist,
-                sums=jax.lax.psum(res.sums, axes),
-                counts=jax.lax.psum(res.counts, axes),
-                energy=jax.lax.psum(res.energy, axes)), reduce_carry(carries)
+            return reduce_step(res), reduce_carry(carries)
     else:
         batched_step_fn = None
 
@@ -365,23 +368,17 @@ def distribute(backend: Backend, axes: Sequence[str]) -> Backend:
     # minibatch_step_fn.
     def minibatch_step_fn(x, c, k, w, carry):
         res, carry = backend.minibatch_step(x, c, k, w, carry)
-        return StepResult(
-            labels=res.labels,
-            min_sqdist=res.min_sqdist,
-            sums=jax.lax.psum(res.sums, axes),
-            counts=jax.lax.psum(res.counts, axes),
-            energy=jax.lax.psum(res.energy, axes)), reduce_carry(carry)
+        return reduce_step(res), reduce_carry(carry)
 
     def stats_fn(x, labels, k):
         sums, counts = backend.stats_fn(x, labels, k)
-        return jax.lax.psum(sums, axes), jax.lax.psum(counts, axes)
+        return psum(sums), psum(counts)
 
     def energy_fn(x, c, labels):
-        return jax.lax.psum(backend.energy_fn(x, c, labels), axes)
+        return psum(backend.energy_fn(x, c, labels))
 
     def all_equal_fn(a, b):
-        neq = jnp.sum((a != b).astype(jnp.int32))
-        return jax.lax.psum(neq, axes) == 0
+        return psum(jnp.sum((a != b).astype(jnp.int32))) == 0
 
     return dataclasses.replace(
         backend,
@@ -390,7 +387,7 @@ def distribute(backend: Backend, axes: Sequence[str]) -> Backend:
         minibatch_step_fn=minibatch_step_fn,
         stats_fn=stats_fn, energy_fn=energy_fn,
         all_equal_fn=all_equal_fn,
-        reduce_scalar=lambda s: jax.lax.psum(s, axes),
+        reduce_scalar=psum,
         axes=axes)
 
 

@@ -28,7 +28,7 @@ from __future__ import annotations
 
 import functools
 import os
-from typing import Optional, Sequence, Union
+from typing import Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -220,7 +220,7 @@ def make_distributed_kmeans(mesh: jax.sharding.Mesh, cfg: KMeansConfig,
     # -- segmented path (host loop over shard_map'd while_loop segments) --
     local = resolve_backend(backend, cfg=cfg, block_n=block_n) \
         if not isinstance(backend, Backend) or not backend.axes else None
-    programs = {}   # (x shape/dtype, c0 shape/dtype) -> (init, seg, specs)
+    programs = {}   # (x shape/dtype, c0 shape/dtype) -> (init, seg, close)
 
     def _segment_programs(x, c0):
         key = (x.shape, str(x.dtype), c0.shape, str(c0.dtype))
@@ -246,13 +246,16 @@ def make_distributed_kmeans(mesh: jax.sharding.Mesh, cfg: KMeansConfig,
             lambda xl, st, end: KM._run_segment(xl, st, end, cfg=cfg,
                                                 backend=ops),
             mesh=mesh, in_specs=(x_spec, specs, rep), out_specs=specs))
-        built = programs[key] = (init, seg, specs)
+        close = jax.jit(shard_map(
+            lambda xl, st: KM._close(xl, st, cfg, ops),
+            mesh=mesh, in_specs=(x_spec, specs), out_specs=specs))
+        built = programs[key] = (init, seg, close)
         return built
 
     def _fit_segmented(x, c0, resume_from):
         KM._no_trace(x, "make_distributed_kmeans fit")
         every = int(checkpoint_every) if checkpoint_every else cfg.max_iter
-        init, seg, _ = _segment_programs(x, c0)
+        init, seg, close = _segment_programs(x, c0)
         x = jax.device_put(x, x_sharding)
         c0 = jax.device_put(c0, rep_sharding)
         if resume_from is None:
@@ -272,7 +275,7 @@ def make_distributed_kmeans(mesh: jax.sharding.Mesh, cfg: KMeansConfig,
                              t, cfg, ops,
                              extra={"mesh": dict(mesh.shape),
                                     "data_axes": list(axes)})
-        return KM._result_from_state(state)
+        return KM._result_from_state(close(x, state))
 
     def fit(x, c0, resume_from=None):
         if not checkpoint_every and checkpoint_dir is None \
@@ -392,18 +395,62 @@ def make_distributed_kmeans_minibatch(mesh: jax.sharding.Mesh,
 
 def shard_dataset(x, mesh: jax.sharding.Mesh,
                   data_axes: Sequence[str] = ("data",)):
-    """Place a host array on the mesh, padding N to the shard count.
+    """Place rows on the mesh, sharded over ``data_axes``, padding N to a
+    multiple of the shard count; returns ``(x_sharded, pad)``.
 
-    Padding rows replicate the final sample: duplicated points only bias the
-    padded copy's cluster weighting, and callers that need exactness should
-    pre-size N; the launcher reports when padding is applied."""
+    Padding rows replicate the final sample: duplicated points only bias
+    the padded copy's cluster weighting, and callers that need exactness
+    should pre-size N; the launcher reports when padding is applied.  A
+    host array is padded on the host.  A device array stays on the
+    devices: one already laid out over the mesh is returned as it is,
+    and a ragged one is padded by a program whose output is the sharded
+    array, so no copy of X passes through the host."""
     import numpy as np
-    n_shards = 1
-    for a in data_axes:
-        n_shards *= mesh.shape[a]
-    n = x.shape[0]
-    pad = (-n) % n_shards
-    if pad:
-        x = np.concatenate([x, np.repeat(x[-1:], pad, axis=0)], axis=0)
-    sharding = NamedSharding(mesh, P(tuple(data_axes)))
-    return jax.device_put(x, sharding), pad
+    axes = tuple(data_axes)
+    pad = (-x.shape[0]) % _mesh_shards(mesh, axes)
+    sharding = NamedSharding(mesh, P(axes))
+    if not isinstance(x, jax.Array):
+        x = np.asarray(x)
+        if pad:
+            x = np.concatenate([x, np.repeat(x[-1:], pad, axis=0)], axis=0)
+        return jax.device_put(x, sharding), pad
+    if not pad:
+        return jax.device_put(x, sharding), 0
+    return _pad_rows(x, pad, sharding), pad
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2))
+def _pad_rows(x, pad: int, sharding):
+    x = jnp.concatenate([x, jnp.repeat(x[-1:], pad, axis=0)], axis=0)
+    return jax.lax.with_sharding_constraint(x, sharding)
+
+
+@functools.lru_cache(maxsize=None)
+def row_take(mesh: jax.sharding.Mesh, data_axes: Tuple[str, ...]):
+    """``take(x, idx) -> x[idx]`` for rows sharded over ``data_axes``, as
+    one jitted program: each shard multiplies a one-hot of the requested
+    rows it holds by its rows, and one psum assembles the (…, d)
+    result, replicated.  Only the picked rows travel; X is never
+    gathered.  Exact: every output element is 1·x plus zeros, which
+    float32 at ``HIGHEST`` sums without rounding.  A gather would do the
+    same work, but on a TPU it first copies the shard into a row-major
+    layout (7.26 GB for 2,025,000 rows of d = 784, which the chip stores
+    column-major); the one-hot product reads the shard as it lies.  One
+    function per (mesh, axes), so a jitted seeding scheme that takes it
+    as a static argument is traced once per layout."""
+    axes = tuple(data_axes)
+
+    def local(xl, idx):
+        n_local = xl.shape[0]
+        j = idx - jax.lax.axis_index(axes) * n_local
+        onehot = (j[..., None] == jnp.arange(n_local)).astype(xl.dtype)
+        rows = jnp.matmul(onehot, xl, precision=jax.lax.Precision.HIGHEST)
+        with jax.named_scope("repro.collective"):
+            return jax.lax.psum(rows, axes)
+
+    @jax.jit
+    def take(x, idx):
+        return shard_map(local, mesh=mesh, in_specs=(P(axes), P()),
+                         out_specs=P())(x, idx)
+
+    return take

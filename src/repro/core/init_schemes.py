@@ -38,19 +38,25 @@ def _validate_seeding(x: jax.Array, k: int, scheme: str) -> None:
             f"samples; need k <= n")
 
 
-def random_init(key: jax.Array, x: jax.Array, k: int,
-                w=None) -> jax.Array:
-    """Uniformly sample K distinct rows of X.  ``w`` (N,) >= 0 biases the
-    draw (p ∝ w) — a zero-weight (padding) row is never picked."""
-    _validate_seeding(x, k, "random_init")
-    p = None if w is None else w / jnp.maximum(jnp.sum(w), 1e-30)
-    idx = jax.random.choice(key, x.shape[0], (k,), replace=False, p=p)
+def _take_rows(x, idx):
     return x[idx]
 
 
-@partial(jax.jit, static_argnames=("k",))
+def random_init(key: jax.Array, x: jax.Array, k: int,
+                w=None, take=None) -> jax.Array:
+    """Uniformly sample K distinct rows of X.  ``w`` (N,) >= 0 biases the
+    draw (p ∝ w) — a zero-weight (padding) row is never picked.
+    ``take(x, idx)`` fetches the picked rows (rows sharded over a mesh:
+    `repro.core.distributed.row_take`)."""
+    _validate_seeding(x, k, "random_init")
+    p = None if w is None else w / jnp.maximum(jnp.sum(w), 1e-30)
+    idx = jax.random.choice(key, x.shape[0], (k,), replace=False, p=p)
+    return (take or _take_rows)(x, idx)
+
+
+@partial(jax.jit, static_argnames=("k", "take"))
 def kmeanspp_init(key: jax.Array, x: jax.Array, k: int,
-                  w=None) -> jax.Array:
+                  w=None, take=None) -> jax.Array:
     """K-Means++: D^2-weighted sequential sampling.
 
     ``w`` (N,) >= 0 makes the sampling SEGMENT-AWARE: the first pick
@@ -58,8 +64,10 @@ def kmeanspp_init(key: jax.Array, x: jax.Array, k: int,
     row — the hierarchy engine's segment padding — is never seeded
     (DESIGN.md §Hierarchy).  ``w=None`` keeps the classic scheme
     bit-for-bit (the unweighted draws use different PRNG primitives, so
-    ``w=ones`` is distributionally equal but not bitwise)."""
+    ``w=ones`` is distributionally equal but not bitwise).  ``take`` as
+    in `random_init`."""
     _validate_seeding(x, k, "kmeanspp_init")
+    take = take or _take_rows
     n = x.shape[0]
     key, sub = jax.random.split(key)
     if w is None:
@@ -69,7 +77,7 @@ def kmeanspp_init(key: jax.Array, x: jax.Array, k: int,
         first = jax.random.categorical(
             sub, jnp.log(jnp.maximum(w / jnp.maximum(jnp.sum(w), 1e-30),
                                      1e-38)))
-    c0 = x[first]
+    c0 = take(x, first)
     mind = jnp.sum((x - c0) ** 2, axis=-1)
 
     def body(carry, key_t):
@@ -85,7 +93,7 @@ def kmeanspp_init(key: jax.Array, x: jax.Array, k: int,
             score = jnp.where(jnp.sum(s) > 0, s, w)
         p = score / jnp.maximum(jnp.sum(score), 1e-30)
         idx = jax.random.categorical(key_t, jnp.log(jnp.maximum(p, 1e-38)))
-        c_new = x[idx]
+        c_new = take(x, idx)
         d_new = jnp.sum((x - c_new) ** 2, axis=-1)
         mind = jnp.minimum(mind, d_new)
         return (mind, idx), c_new
@@ -253,7 +261,7 @@ def make_init(name: str):
 
 
 def batched_init(name: str, keys: jax.Array, x: jax.Array,
-                 k: int, weights=None) -> jax.Array:
+                 k: int, weights=None, take=None) -> jax.Array:
     """Seed R restarts at once: (R, 2) keys -> (R, K, d) centroid stacks.
 
     ``x`` is (N, d) shared across restarts, or (R, N, d) one dataset per
@@ -265,8 +273,19 @@ def batched_init(name: str, keys: jax.Array, x: jax.Array,
     ``weights`` (R, N) >= 0 makes the seeding segment-aware (the
     hierarchy engine's padded sub-problems: padding rows weigh 0 and are
     never seeded) — supported for the weighted schemes random/kmeans++
-    only."""
+    only.
+
+    ``take(x, idx)`` fetches picked rows for the schemes that only pick
+    rows (random, kmeans++): with rows sharded over a mesh,
+    `repro.core.distributed.row_take` moves the picked rows alone.  The
+    other schemes would gather X and refuse it."""
     fn = make_init(name)
+    if take is not None:
+        if name not in ("random", "kmeans++"):
+            raise ValueError(
+                f"init={name!r} cannot seed from rows sharded over a mesh "
+                f"without gathering them; use 'random' or 'kmeans++'")
+        fn = partial(fn, take=take)
     x_axis = 0 if x.ndim == 3 else None
     if x_axis == 0 and x.shape[0] != keys.shape[0]:
         raise ValueError(

@@ -357,10 +357,83 @@ def _no_trace(x, who: str):
 def _result_from_state(state: _LoopState) -> KMeansResult:
     # Iteration count convention of the paper's "a/b": b counts the initial
     # C^1 = G(C^0) plus every fully-executed loop body; the body that merely
-    # *detects* convergence (line 4-5 early return) is not counted.
+    # *detects* convergence (line 4-5 early return) is not counted.  A
+    # capped state must have been through `_close` (or `_close_batched`).
     n_iter = state.t + jnp.where(state.converged, 0, 1)
     return KMeansResult(state.c, state.labels, state.e_last,
                         n_iter, state.n_acc, state.converged)
+
+
+def _close(x, state: _LoopState, cfg: KMeansConfig,
+           backend: Backend) -> _LoopState:
+    """End a solve that stopped without converging (``max_iter``, or an
+    early-stop hook) on a point it has evaluated.
+
+    Such a loop exits holding C^{t+1}, the next Anderson (or Lloyd)
+    iterate, beside the labels and energy of the point before it.  The
+    closing evaluation does what the next loop body would begin with:
+    assign C^{t+1}; if the energy guard would reject it, assign the
+    fallback C_AU^{t+1} instead.  The state then carries that point with
+    its own labels and energy.  It is not an iteration: ``t`` and the
+    counters stay.  A converged state passes through untouched, and
+    under ``lax.cond`` pays no step."""
+    k = cfg.k
+
+    def evaluate(st: _LoopState) -> _LoopState:
+        with jax.named_scope("repro.fit.final"):
+            res, carry = backend.step(x, st.c, k, st.carry)
+
+            def keep(carry):
+                return st.c, res.labels, res.energy
+
+            def revert(carry):
+                res_f, _ = backend.step(x, st.c_au, k, carry)
+                return st.c_au, res_f.labels, res_f.energy
+
+            c, labels, e = jax.lax.cond(res.energy < st.e_prev, keep,
+                                        revert, carry)
+        return st._replace(c=c, labels=labels, e_last=e)
+
+    return jax.lax.cond(state.converged, lambda st: st, evaluate, state)
+
+
+def _close_batched(x, state: _LoopState, cfg: KMeansConfig,
+                   backend: Backend, x_batched: bool,
+                   w=None) -> _LoopState:
+    """`_close` for a batched state (leading R axis): one batched step at
+    every problem's C^{t+1}, one more at C_AU^{t+1} only when some open
+    problem's guard rejects, each problem keeping what its own guard
+    picks.  Converged problems keep their state bit for bit, and when
+    all have converged neither step runs."""
+    k = cfg.k
+    open_ = ~state.converged                                    # (R,)
+
+    def evaluate(st: _LoopState) -> _LoopState:
+        with jax.named_scope("repro.fit.final"):
+            res, carry = backend.batched_step(x, st.c, k, st.carry,
+                                              x_batched=x_batched, w=w)
+            keep = res.energy < st.e_prev
+
+            def revert(carry):
+                res_f, _ = backend.batched_step(x, st.c_au, k, carry,
+                                                x_batched=x_batched, w=w)
+                return res_f.labels, res_f.energy
+
+            lab_f, e_f = jax.lax.cond(
+                jnp.any(open_ & ~keep), revert,
+                lambda carry: (res.labels, res.energy), carry)
+            closed = st._replace(
+                c=_tree_select_rows(keep, st.c, st.c_au),
+                labels=_tree_select_rows(keep, res.labels, lab_f),
+                e_last=jnp.where(keep, res.energy, e_f))
+        return _tree_select_rows(open_, closed, st)
+
+    return jax.lax.cond(jnp.any(open_), evaluate, lambda st: st, state)
+
+
+_close_jit = jax.jit(_close, static_argnames=("cfg", "backend"))
+_close_batched_jit = jax.jit(_close_batched,
+                             static_argnames=("cfg", "backend", "x_batched"))
 
 
 def _aa_kmeans_segmented(x, c0, cfg: KMeansConfig, bk: Backend,
@@ -409,7 +482,7 @@ def _aa_kmeans_segmented(x, c0, cfg: KMeansConfig, bk: Backend,
     finally:
         if writer is not None:
             writer.close()   # drain + join; a failed write fails the run
-    return _result_from_state(state)
+    return _result_from_state(_close_jit(x, state, cfg, bk))
 
 
 def aa_kmeans(x: jax.Array, c0: jax.Array, cfg: KMeansConfig,
@@ -425,6 +498,9 @@ def aa_kmeans(x: jax.Array, c0: jax.Array, cfg: KMeansConfig,
               sync_writes: bool = False,
               reorder=False) -> KMeansResult:
     """Jit-able Algorithm 1.  ``cfg`` is static; x (N,d); c0 (K,d).
+
+    A solve that stops at ``max_iter`` unconverged returns a point it
+    evaluated, with that point's labels and energy (`_close`).
 
     ``backend`` selects the engine ("dense" | "blocked" | "pallas" |
     "fused" | "hamerly" | "elkan" | "yinyang" | "fused_bounds", a Backend
@@ -479,7 +555,7 @@ def aa_kmeans(x: jax.Array, c0: jax.Array, cfg: KMeansConfig,
 
     state = _init_state(x, c0, cfg, bk)
     state = jax.lax.while_loop(cond, body, state)
-    return _result_from_state(state)
+    return _result_from_state(_close(x, state, cfg, bk))
 
 
 def aa_kmeans_jit(x, c0, cfg: KMeansConfig, ops: Optional[LloydOps] = None,
@@ -735,6 +811,21 @@ def aa_kmeans_batched(x: jax.Array, c0s: jax.Array, cfg: KMeansConfig,
             resume_from, checkpoint_cb, keep_last_n, keep_every_m,
             metrics, sync_writes, weights=weights)
 
+    return aa_kmeans_batched_close(
+        x, aa_kmeans_batched_loop(x, c0s, cfg, bk, weights), cfg, bk,
+        weights)
+
+
+def aa_kmeans_batched_loop(x: jax.Array, c0s: jax.Array, cfg: KMeansConfig,
+                           backend: BackendLike = None,
+                           weights=None) -> _LoopState:
+    """The first half of `aa_kmeans_batched` (no checkpointing): the
+    batched solve's loop, returning the R-batched state it exits with.
+    `aa_kmeans_batched_close` turns that state into the result.  Jitted
+    as two programs, the loop compiles without the closing evaluation's
+    buffers beside it."""
+    bk = resolve_backend(backend, cfg=cfg)
+    x_axis = 0 if x.ndim == 3 else None
     states = _init_batched_state(x, c0s, cfg, bk, x_axis, w=weights)
 
     def active(bst: _BatchedState):
@@ -752,8 +843,18 @@ def aa_kmeans_batched(x: jax.Array, c0s: jax.Array, cfg: KMeansConfig,
         # frozen row-wise, so the shared loop cannot perturb it.
         return _tree_select_rows(active(bst), new_bst, bst)
 
-    states = jax.lax.while_loop(cond, body, states).inner
-    return _result_from_state(states)
+    return jax.lax.while_loop(cond, body, states).inner
+
+
+def aa_kmeans_batched_close(x: jax.Array, states: _LoopState,
+                            cfg: KMeansConfig, backend: BackendLike = None,
+                            weights=None) -> KMeansResult:
+    """The second half of `aa_kmeans_batched`: the closing evaluation of
+    the problems that stopped at ``max_iter`` (`_close_batched`), and the
+    result, every leaf with its leading R axis."""
+    bk = resolve_backend(backend, cfg=cfg)
+    return _result_from_state(_close_batched(
+        x, states, cfg, bk, x_batched=(x.ndim == 3), w=weights))
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "backend", "x_axis"))
@@ -825,7 +926,8 @@ def _aa_kmeans_batched_segmented(x, c0s, cfg: KMeansConfig, bk: Backend,
     finally:
         if writer is not None:
             writer.close()
-    return _result_from_state(bst.inner)
+    return _result_from_state(_close_batched_jit(
+        x, bst.inner, cfg, bk, x_batched=(x_axis == 0), w=weights))
 
 
 def select_best(results: KMeansResult, groups=None,
@@ -1238,6 +1340,9 @@ def aa_kmeans_traced(x: jax.Array, c0: jax.Array, cfg: KMeansConfig,
                            "skipped_frac": float(bs.skipped_frac)})
             scalars.update(bstats[-1])
         mx.log_scalars(len(energies), scalars)
+    if not converged:
+        close = _close_jit if jit_iteration else _close
+        state = close(x, state, cfg, bk)
     jax.block_until_ready(state.c)
     wall = time.perf_counter() - t0
 
