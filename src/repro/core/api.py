@@ -90,12 +90,16 @@ def fit_program_traces() -> int:
 _MESH_FIT_PROGRAM_TRACES = 0
 
 
+@functools.lru_cache(maxsize=16)
 def _mesh_fit_program(mesh, cfg: KMeansConfig, data_axes, backend):
     """`AAKMeans.fit`'s program on a mesh: the batched solve with rows
     sharded over ``data_axes`` and the best restart picked on device
-    (`make_distributed_kmeans_batched`).  Built anew on every fit, so
-    every fit traces and lowers it again; `mesh_fit_program_traces`
-    counts those traces."""
+    (`make_distributed_kmeans_batched`).  Built once per configuration,
+    the key ``(mesh, cfg, data_axes, backend)``: later fits of that
+    configuration get the same jitted program back, and JAX's jit cache
+    keys the shapes and shardings of ``x`` and ``c0s``, so a fit traces
+    and lowers it only when one of those is new.  ``data_axes`` is a
+    tuple and ``backend`` stays unresolved, as in `_fit_program`."""
     solve = make_distributed_kmeans_batched(mesh, cfg, data_axes,
                                             backend=backend, pick_best=True)
 
@@ -110,7 +114,9 @@ def _mesh_fit_program(mesh, cfg: KMeansConfig, data_axes, backend):
 
 def mesh_fit_program_traces() -> int:
     """How many times `AAKMeans.fit`'s mesh program has been traced in
-    this process; over the number of mesh fits, the traces per fit."""
+    this process: once per configuration (`_mesh_fit_program`'s key and
+    the shapes of the fit's operands), so over the number of mesh fits,
+    the share of fits that missed the cache."""
     return _MESH_FIT_PROGRAM_TRACES
 
 
@@ -465,7 +471,8 @@ class AAKMeans:
         # does not multiply dispatch/transfer cost.
         with jax.profiler.TraceAnnotation("repro.fit.solve"):
             if self.mesh is not None:
-                best = _mesh_fit_program(self.mesh, cfg, self.data_axes,
+                best = _mesh_fit_program(self.mesh, cfg,
+                                         tuple(self.data_axes),
                                          self.backend)(x, c0s)
             elif self.metrics is not None:
                 # segmented (host-loop) driver: metrics need host boundaries

@@ -1,13 +1,15 @@
 """`AAKMeans(mesh=…).fit` on rows that exist only sharded, on four CPU
 host devices (each test a subprocess that sets XLA_FLAGS before jax is
 imported): the answer against the plain reference and against the
-one-device fit, one trace of the mesh program per fit, and no program
-that gathers X (seeding, the fit, ragged padding)."""
+one-device fit, one trace of the mesh program per configuration, and
+no program that gathers X (seeding, the fit, ragged padding)."""
 
 import os
 import subprocess
 import sys
 from pathlib import Path
+
+import pytest
 
 ROOT = Path(__file__).resolve().parents[1]
 
@@ -51,7 +53,7 @@ est = dict(n_clusters=16, init="random", max_iter=5, seed=11)
 t0 = mesh_fit_program_traces()
 m = AAKMeans(mesh=mesh, **est).fit(x)
 m2 = AAKMeans(mesh=mesh, **dict(est, seed=12)).fit(x)
-assert mesh_fit_program_traces() - t0 == 2      # one trace per fit today
+assert mesh_fit_program_traces() - t0 == 1      # one per configuration
 assert m.labels_.sharding.is_equivalent_to(rows, 1)   # labels stay sharded
 want, mind = assignment_ref(jnp.asarray(x_host), m.centroids_)
 np.testing.assert_array_equal(np.asarray(m.labels_), np.asarray(want))
@@ -76,8 +78,22 @@ def test_no_mesh_fit_program_gathers_x():
 import re
 from repro.core import AAKMeans
 from repro.core.api import _mesh_fit_program
-from repro.core.distributed import row_take
+from repro.core.distributed import make_distributed_kmeans_batched, row_take
 from repro.core.init_schemes import batched_init
+
+# a compiled module as the device runs it: no module name, no debug
+# metadata, and every instruction and computation renamed in order of
+# first appearance (the jitted wrapper calls the program that
+# make_distributed_kmeans_batched returns, and the inlined call shifts
+# XLA's numbering and nothing else)
+def program_text(hlo):
+    hlo = re.sub(r", metadata=\\{[^}]*\\}", "", hlo)
+    head = re.sub(r"^HloModule \\S+,", "HloModule _,", hlo.splitlines()[0])
+    names = {}
+    return head + re.sub(r"\\b[A-Za-z_][\\w\\-]*\\.\\d+\\b",
+                         lambda m: names.setdefault(m.group(0),
+                                                    f"v{len(names)}"),
+                         hlo[hlo.index("\\n%"):])
 
 def gathered(hlo):
     sizes = [0]
@@ -95,12 +111,59 @@ for init in ("random", "kmeans++"):
     assert gathered(seed.lower(keys, x).compile().as_text()) <= 8, init
 cfg = AAKMeans(n_clusters=16, max_iter=5)._config()
 c0s = jax.ShapeDtypeStruct((2, 16, 784), jnp.float32)
-hlo = _mesh_fit_program(mesh, cfg, ("data",), "dense").lower(
-    x, c0s).compile().as_text()
+program = _mesh_fit_program(mesh, cfg, ("data",), "dense")
+assert _mesh_fit_program(mesh, cfg, ("data",), "dense") is program
+hlo = program.lower(x, c0s).compile().as_text()
 assert gathered(hlo) == 0 and "all-reduce" in hlo
+fresh = make_distributed_kmeans_batched(mesh, cfg, ("data",),
+                                        backend="dense", pick_best=True)
+assert program_text(hlo) == program_text(
+    fresh.lower(x, c0s).compile().as_text())
 print("NO_GATHER_OK")
 """)
     assert "NO_GATHER_OK" in out
+
+
+@pytest.mark.parametrize("case", ["max_iter", "two_devices",
+                                  "two_devices_refit"])
+def test_mesh_fit_program_rebuilds_per_configuration(case):
+    """After a fit on the four-device mesh, a fit with another
+    ``max_iter`` or on a two-device mesh traces a program of its own,
+    and a second fit on the two-device mesh, differing only in seed,
+    reuses that one; every fit's labels are the plain reference's
+    nearest centroids, so no configuration is served a program built
+    for another."""
+    out = _run(f"""
+from repro.core import AAKMeans
+from repro.core.api import mesh_fit_program_traces
+from repro.data.synthetic import make_blobs
+from repro.kernels.ref import assignment_ref
+
+case = {case!r}
+x_host = make_blobs(2048, 32, 6, seed=5, spread=1.5).astype(np.float32)
+mesh2 = jax.make_mesh((2,), ("data",), devices=jax.devices()[:2],
+                      axis_types=(jax.sharding.AxisType.Auto,))
+est = dict(n_clusters=8, init="random", max_iter=5, seed=3)
+
+def traced_fit(mesh_, **over):
+    t0 = mesh_fit_program_traces()
+    m = AAKMeans(mesh=mesh_, **dict(est, **over)).fit(x_host)
+    want, _ = assignment_ref(jnp.asarray(x_host), m.centroids_)
+    np.testing.assert_array_equal(np.asarray(m.labels_), np.asarray(want))
+    return mesh_fit_program_traces() - t0
+
+assert traced_fit(mesh) == 1
+if case == "max_iter":
+    assert traced_fit(mesh, max_iter=4) == 1
+    assert traced_fit(mesh, seed=4) == 0
+else:
+    assert traced_fit(mesh2) == 1
+    assert traced_fit(mesh) == 0
+    if case == "two_devices_refit":
+        assert traced_fit(mesh2, seed=4) == 0
+print("REBUILD_OK")
+""")
+    assert "REBUILD_OK" in out
 
 
 def test_shard_dataset_pads_a_ragged_device_array_on_the_device():
