@@ -64,7 +64,7 @@ def run(checkpoint_every: int = 10, smoke: bool = False) -> dict:
     every = max(1, int(checkpoint_every))
 
     # the monolithic baseline is the jitted whole-solve program, like any
-    # production caller would run it (aa_kmeans_jit idiom)
+    # production caller would run it
     mono = jax.jit(lambda xx, cc: aa_kmeans(xx, cc, cfg))
     ref = mono(x, c0)
     t_mono = _solve_time(lambda: mono(x, c0))

@@ -3,13 +3,15 @@
 Public surface:
     AAKMeans              — sklearn-shaped estimator (batched multi-restart)
     MiniBatchAAKMeans     — streaming estimator (partial_fit / chunked fit)
-    aa_kmeans             — jit-able Algorithm 1 (lax.while_loop)
+    aa_kmeans             — jit-able Algorithm 1: one solve on the
+                            split-phase loop body (lax.while_loop)
     aa_kmeans_batched     — R restarts/problems in one device program
     aa_kmeans_minibatch   — streaming chunked driver (DESIGN.md §Streaming)
     aa_kmeans_minibatch_streamed — host-source epoch driver with prefetch
     ReorderConfig/reorder_backend — locality engine (DESIGN.md §Locality)
     select_best           — on-device best-of-R selection
-    aa_kmeans_traced      — instrumented driver (per-iteration stats)
+    aa_kmeans_traced      — the same trips from a Python loop, recording
+                            per-iteration stats
     lloyd_kmeans          — classical Lloyd baseline
     hamerly_kmeans        — Hamerly-bound Lloyd baseline
     KMeansConfig/AAConfig/MiniBatchConfig — solver configuration

@@ -15,7 +15,7 @@ oracle.
 
 Registered backends:
 
-    dense        — jnp reference semantics (the oracle; legacy DENSE_OPS)
+    dense        — jnp reference semantics (the oracle)
     blocked      — row-blocked distances, bounded (block_n, K) intermediate
     pallas       — separate tiled assignment/update kernels (decomposed)
     fused        — single-pass Pallas kernel: one X read per accepted
@@ -44,9 +44,8 @@ rest go to the inner backend's factory.
 
 from repro.core.backends.base import (Backend, Precision,        # noqa: F401
                                       StepResult, backend_names,
-                                      distribute, from_lloyd_ops,
-                                      get_backend, instrument,
-                                      register_backend)
+                                      distribute, get_backend,
+                                      instrument, register_backend)
 from repro.core.backends.bounds import BoundStats                # noqa: F401
 from repro.core.backends.dense import (blocked_backend,          # noqa: F401
                                        dense_backend)

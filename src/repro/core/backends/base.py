@@ -2,10 +2,10 @@
 
 The paper's Sec-2.1 overhead argument prices one Algorithm-1 iteration at
 one application of the fixed-point map G = Update ∘ Assign — i.e. one pass
-over X — plus O(m·K·d) for the Anderson solve.  The legacy `LloydOps`
-container exposed assign/update/energy as separate call sites, which forced
-the driver into two to three X passes per iteration and made the fused
-single-pass Pallas kernel unusable.  A `Backend`'s core op is instead
+over X — plus O(m·K·d) for the Anderson solve.  Separate assign/update/
+energy call sites would force the driver into two to three X passes per
+iteration and make the fused single-pass Pallas kernel unusable.  A
+`Backend`'s core op is instead
 
     step(x, c, k, carry) -> (StepResult(labels, min_sqdist, sums, counts,
                                         energy), carry)
@@ -44,14 +44,13 @@ Orthogonal axes, composable by construction:
 from __future__ import annotations
 
 import dataclasses
-import weakref
 from typing import Any, Callable, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from repro.core import lloyd
-from repro.core.lloyd import AssignResult, LloydOps, energy_from_mindist
+from repro.core.lloyd import AssignResult
 
 
 class StepResult(NamedTuple):
@@ -108,13 +107,6 @@ def _default_init_carry(x, c, k):
     return ()
 
 
-def _default_finalize(x, res: StepResult, k: int, c_prev: jax.Array):
-    """G(C) from the step's partial stats — no further pass over X."""
-    c_new = lloyd.update_from_sums(res.sums, res.counts,
-                                   c_prev.astype(res.sums.dtype))
-    return c_new.astype(c_prev.dtype)
-
-
 def _default_all_equal(a, b):
     return jnp.all(a == b)
 
@@ -128,9 +120,9 @@ class Backend:
     """A local-compute engine for Algorithm 1, keyed by the step primitive.
 
     Instances are immutable and hashable, so a Backend can be a static jit
-    argument exactly like the legacy LloydOps container.  Use the
-    module-level factories / `get_backend` rather than constructing
-    directly; `distribute` wraps any instance for a shard_map mesh.
+    argument.  Use the module-level factories / `get_backend` rather than
+    constructing directly; `distribute` wraps any instance for a
+    shard_map mesh.
     """
     name: str
     # (x, c, k, carry) -> (StepResult, carry): ONE logical pass over X.
@@ -162,7 +154,7 @@ class Backend:
     # (the update half of G; used by the derived update op and by
     # distribute's psum wrapping).
     stats_fn: Callable = None
-    # (x, c) -> AssignResult: standalone assignment (predict / legacy).
+    # (x, c) -> AssignResult: standalone assignment (predict).
     assign_fn: Callable = None
     # (x, c, labels) -> scalar: FULLY-REDUCED energy of a fixed assignment
     # (distributed backends psum inside; do not compose with reduce_scalar).
@@ -170,8 +162,6 @@ class Backend:
     all_equal_fn: Callable = _default_all_equal
     reduce_scalar: Callable = _identity
     init_carry_fn: Callable = _default_init_carry
-    # (x, res, k, c_prev) -> next centroids; default consumes res.sums.
-    finalize_fn: Callable = _default_finalize
     precision: Precision = DEFAULT_PRECISION
     # mesh axes this backend's step already psum-reduces over; set by
     # `distribute` — empty for local backends.
@@ -228,7 +218,10 @@ class Backend:
         return self.init_carry_fn(x, c, k)
 
     def centroids_from_step(self, x, res: StepResult, k: int, c_prev):
-        return self.finalize_fn(x, res, k, c_prev)
+        """G(C) from the step's partial stats — no further pass over X."""
+        c_new = lloyd.update_from_sums(res.sums, res.counts,
+                                       c_prev.astype(res.sums.dtype))
+        return c_new.astype(c_prev.dtype)
 
     # -- derived ops ------------------------------------------------------
 
@@ -389,54 +382,6 @@ def distribute(backend: Backend, axes: Sequence[str]) -> Backend:
         all_equal_fn=all_equal_fn,
         reduce_scalar=psum,
         axes=axes)
-
-
-# ---------------------------------------------------------------------------
-# Legacy LloydOps adapter (deprecation shim)
-# ---------------------------------------------------------------------------
-
-_OPS_ADAPTERS: "weakref.WeakKeyDictionary[LloydOps, Backend]" = \
-    weakref.WeakKeyDictionary()
-
-
-def from_lloyd_ops(ops: LloydOps) -> Backend:
-    """Adapt a legacy LloydOps container to the Backend protocol.
-
-    The legacy update_fn may hide reductions (the old distributed ops psum
-    inside it), so the step's sums/counts are the *local* cluster stats and
-    `centroids_from_step` routes through ops.update_fn — preserving the old
-    container's exact semantics and cost (the stats are dead code under jit
-    on this path).  New code should use `get_backend` / `distribute`.
-
-    Adapters are memoised per LloydOps instance (weakly, so factories that
-    build a fresh container per call do not accumulate entries) to keep the
-    returned object identity stable for jit's static-argument cache.
-    """
-    cached = _OPS_ADAPTERS.get(ops)
-    if cached is not None:
-        return cached
-
-    def step_fn(x, c, k, carry):
-        res = ops.assign_fn(x, c)
-        sums, counts = lloyd.cluster_sums(x.astype(jnp.float32), res.labels,
-                                          k)
-        e = ops.reduce_scalar(energy_from_mindist(res.min_sqdist))
-        return StepResult(res.labels, res.min_sqdist, sums, counts, e), carry
-
-    def finalize_fn(x, res, k, c_prev):
-        return ops.update_fn(x, res.labels, k, c_prev)
-
-    def stats_fn(x, labels, k):
-        return lloyd.cluster_sums(x.astype(jnp.float32), labels, k)
-
-    backend = Backend(name="lloyd-ops-shim", step_fn=step_fn,
-                      stats_fn=stats_fn, assign_fn=ops.assign_fn,
-                      energy_fn=ops.energy_fn,
-                      all_equal_fn=ops.all_equal_fn,
-                      reduce_scalar=ops.reduce_scalar,
-                      finalize_fn=finalize_fn)
-    _OPS_ADAPTERS[ops] = backend
-    return backend
 
 
 # ---------------------------------------------------------------------------

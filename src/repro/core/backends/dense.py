@@ -2,8 +2,8 @@
 
 `dense` is the semantic oracle every other backend is tested against: the
 MXU-friendly |x|^2 - 2 x.c + |c|^2 distance expansion plus segment-sum
-cluster stats — exactly the arithmetic of the legacy DENSE_OPS path, so the
-step-driven solver reproduces the old trajectories bit-for-bit at f32.
+cluster stats — exactly the arithmetic of `lloyd.assign` and
+`lloyd.cluster_sums`.
 
 `blocked` evaluates the distance rows in fixed-size blocks so the (N, K)
 intermediate never materialises — the pure-JAX analogue of the Pallas

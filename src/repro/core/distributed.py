@@ -35,12 +35,11 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import kmeans as KM
-from repro.core import lloyd, serialize
+from repro.core import serialize
 from repro.core.backends import Backend, distribute
 from repro.core.kmeans import (KMeansConfig, KMeansResult, aa_kmeans,
                                aa_kmeans_batched, aa_kmeans_minibatch,
                                resolve_backend, select_best)
-from repro.core.lloyd import LloydOps
 from repro.core.minibatch import MiniBatchConfig, MiniBatchResult
 from repro.kernels import tiles
 
@@ -56,35 +55,6 @@ def shard_map(f, /, **kwargs):
     is off.  The check changes no value, only what is verified."""
     kwargs.setdefault("check_vma", not tiles.interpret_default())
     return jax.shard_map(f, **kwargs)
-
-
-def distributed_lloyd_ops(data_axes: Sequence[str],
-                          block_n: int = 0) -> LloydOps:
-    """DEPRECATED: LloydOps whose update/energy/convergence reduce over
-    ``data_axes``.  Superseded by ``distribute(backend, axes)``; kept so
-    legacy injection sites keep working.  Must be called *inside* shard_map
-    with x as the local shard and c replicated."""
-    axes = tuple(data_axes)
-
-    def assign_fn(x, c):
-        return lloyd.assign(x, c, block_n=block_n)
-
-    def update_fn(x, labels, k, c_prev):
-        sums, counts = lloyd.cluster_sums(x, labels, k)
-        sums = jax.lax.psum(sums, axes)
-        counts = jax.lax.psum(counts, axes)
-        return lloyd.update_from_sums(sums, counts, c_prev)
-
-    def energy_fn(x, c, labels):
-        return jax.lax.psum(lloyd.energy(x, c, labels), axes)
-
-    def all_equal_fn(a, b):
-        neq = jnp.sum((a != b).astype(jnp.int32))
-        return jax.lax.psum(neq, axes) == 0
-
-    return LloydOps(assign_fn=assign_fn, update_fn=update_fn,
-                    energy_fn=energy_fn, all_equal_fn=all_equal_fn,
-                    reduce_scalar=lambda s: jax.lax.psum(s, axes))
 
 
 def _mesh_shards(mesh, axes) -> int:

@@ -1,10 +1,15 @@
 """Algorithm 1 of the paper: Anderson acceleration for the K-Means algorithm.
 
-Two drivers over the same primitives:
+One loop body, `_complete_iteration`, completes an Algorithm-1 iteration
+from one backend step; a rejected iterate leaves the iteration pending
+and the next step, at the fallback C_AU^t, completes it (DESIGN.md
+§Split-phase body).  The drivers over it:
 
-  * ``aa_kmeans``        — fully jit-able ``lax.while_loop`` implementation
-                           (production path; runs unchanged under shard_map
-                           distribution and with Pallas kernel backends).
+  * ``aa_kmeans``        — one solve as jit-able ``lax.while_loop``
+                           segments (`_run_segment`; runs unchanged under
+                           shard_map distribution and with Pallas kernel
+                           backends).
+  * ``aa_kmeans_batched`` — R restarts / problems in one device program.
   * ``aa_kmeans_traced`` — Python-loop driver that records the per-iteration
                            statistics the paper reports (accepted / total
                            iterations, energy trace, m trace, wall time);
@@ -12,9 +17,8 @@ Two drivers over the same primitives:
   * ``aa_kmeans_minibatch`` — streaming epoch driver over chunked data
                            (state machine in repro.core.minibatch;
                            DESIGN.md §Streaming).
-  * ``aa_kmeans_batched`` — R restarts / problems in one device program.
 
-Both consume a `Backend` (repro.core.backends) whose core op is the
+All consume a `Backend` (repro.core.backends) whose core op is the
 single-pass ``step(x, c) -> StepResult``, so one *accepted* Algorithm-1
 iteration costs exactly one pass over X (the paper's Sec-2.1 cost model):
 the step's assignment doubles as the energy evaluation AND as the cluster
@@ -50,8 +54,7 @@ import jax.numpy as jnp
 
 from repro.core import anderson, serialize
 from repro.core.anderson import AAConfig, AAState
-from repro.core.backends import Backend, from_lloyd_ops, get_backend
-from repro.core.lloyd import DENSE_OPS, LloydOps
+from repro.core.backends import Backend, get_backend
 from repro.core.locality import maybe_reorder
 from repro.core.minibatch import (MiniBatchConfig, MiniBatchResult,
                                   guard_pick, minibatch_init,
@@ -96,18 +99,17 @@ class _LoopState(NamedTuple):
 BackendLike = Union[str, Backend, None]
 
 
-def resolve_backend(backend: BackendLike, ops: Optional[LloydOps] = None,
+def resolve_backend(backend: BackendLike,
                     cfg: Optional[KMeansConfig] = None,
                     block_n: int = 0) -> Backend:
-    """Resolve the (backend=, ops=) pair the solver entry points accept —
-    the single backend-selection policy for both the local and the
-    distributed drivers.
+    """Resolve the ``backend=`` the solver entry points accept — the single
+    backend-selection policy for both the local and the distributed
+    drivers.
 
-    Priority: an explicit Backend instance wins; a registry name is looked
-    up (with "dense"/"blocked" promoted to the row-blocked engine when a
-    block size is configured — via ``block_n`` or ``cfg.block_n``); a
-    non-default legacy LloydOps is adapted through the deprecation shim;
-    otherwise the dense engine."""
+    A Backend instance is used as-is; a registry name is looked up (with
+    "dense"/"blocked" promoted to the row-blocked engine when a block size
+    is configured — via ``block_n`` or ``cfg.block_n``); None is the dense
+    engine, row-blocked under the same rule."""
     block_n = block_n or (cfg.block_n if cfg is not None else 0)
     if isinstance(backend, Backend):
         return backend
@@ -115,14 +117,10 @@ def resolve_backend(backend: BackendLike, ops: Optional[LloydOps] = None,
         if backend in ("dense", "blocked") and block_n:
             return get_backend("blocked", block_n=block_n)
         return get_backend(backend)
-    if isinstance(backend, LloydOps):   # migration path off the ops= param
-        return from_lloyd_ops(backend)
     if backend is not None:
         raise TypeError(
-            f"backend= expects a registry name, a Backend, or a legacy "
-            f"LloydOps; got {type(backend).__name__}")
-    if ops is not None and ops is not DENSE_OPS:
-        return from_lloyd_ops(ops)
+            f"backend= expects a registry name or a Backend; got "
+            f"{type(backend).__name__}")
     if block_n:
         return get_backend("blocked", block_n=block_n)
     return get_backend("dense")
@@ -158,80 +156,91 @@ def _init_state(x, c0, cfg: KMeansConfig, backend: Backend,
         carry=carry)
 
 
-def _iteration(x, state: _LoopState, cfg: KMeansConfig, backend: Backend):
-    """One body of Algorithm 1's for-loop (lines 3-19) — ONE pass over X
-    when the accelerated iterate is accepted, two when it reverts."""
+class _BatchedState(NamedTuple):
+    """A trip's loop carry: the single solve's (`_trip`) and, with a
+    leading R axis on every leaf, the batched solve's."""
+    inner: _LoopState
+    # True while an Algorithm-1 iteration is half-done: the accelerated
+    # iterate was rejected and the fallback step has not run yet.
+    pending: jax.Array
+
+
+def _tree_where(flag, on_true, on_false):
+    """Leaf-wise select on a scalar flag (broadcasts over any leaf shape)."""
+    return jax.tree_util.tree_map(
+        lambda a, b: jnp.where(flag, a, b), on_true, on_false)
+
+
+def _complete_iteration(x, res, carry, bst: _BatchedState,
+                        cfg: KMeansConfig, backend: Backend,
+                        w=None) -> _BatchedState:
+    """Everything in Algorithm 1's loop body *after* the backend step, on
+    one problem's (unbatched) state: the single solve's `_trip` calls it
+    as is, the batched `_batched_body` vmaps it."""
+    st, pending = bst.inner, bst.pending
     k = cfg.k
+    c_eval = jnp.where(pending, st.c_au, st.c)
 
-    # Lines 3 + 7 + 16 fused: P^t = Assign(X, C^t), E^t = E(P^t, C^t) and
-    # the cluster stats of Update(X, P^t), all from a single step.
-    res, carry = backend.step(x, state.c, k, state.carry)
-    p_t, c_t, e_assign = res.labels, state.c, res.energy
+    # Line 4 (phase A only): the revert step never checks convergence.
+    # Under per-problem weights the check is MASKED: a padding row (w = 0)
+    # never holds up convergence — its label chases centroids it does not
+    # influence, so it may flip forever on ties while the real rows are
+    # long settled (DESIGN.md §Hierarchy).
+    if w is None:
+        lab_now, lab_prev = res.labels, st.p_prev
+    else:
+        live = w > 0
+        lab_now = jnp.where(live, res.labels, 0)
+        lab_prev = jnp.where(live, st.p_prev, 0)
+    conv_now = jnp.logical_and(~pending,
+                               backend.all_equal(lab_now, lab_prev))
+    # Lines 7-11 (phase A only): m adjusts before the revert decision.
+    aa_adj = anderson.adjust_m(st.aa, res.energy, st.e_prev, st.e_prev2,
+                               cfg.aa)
+    accepted = jnp.logical_and(~pending, res.energy < st.e_prev)
+    complete = jnp.logical_or(pending, accepted)
 
-    # Line 4: convergence <=> identical assignment.  Algorithm 1 returns
-    # (P^t, C^t) at line 5 *before* doing any further work.
-    converged = backend.all_equal(p_t, state.p_prev)
+    # Iteration completion (phase-A-accepted or phase-B): lines 16-19 from
+    # the step's stats.  In phase B the window was already adjusted when
+    # the iterate was rejected, so push into the stored state.
+    aa_for_push = _tree_where(pending, st.aa, aa_adj)
+    c_au_next = backend.centroids_from_step(x, res, k, c_eval)
+    g_flat = c_au_next.reshape(-1)
+    f_flat = g_flat - c_eval.reshape(-1)
+    if cfg.accelerated:
+        aa_pushed, c_next_flat, _, _ = anderson.aa_push_and_solve(
+            aa_for_push, f_flat, g_flat, cfg.aa)
+        c_next = c_next_flat.reshape(st.c.shape)
+    else:
+        aa_pushed, c_next = aa_for_push, c_au_next
 
-    def _finish(carry):
-        new_state = state._replace(converged=jnp.array(True), labels=p_t,
-                                   e_last=e_assign, t=state.t + 1,
-                                   carry=carry)
-        return new_state, jnp.array(False), e_assign
+    st_complete = _LoopState(
+        c=c_next, c_au=c_au_next, p_prev=res.labels,
+        e_prev=res.energy, e_prev2=st.e_prev, aa=aa_pushed,
+        t=st.t + 1,
+        n_acc=st.n_acc + accepted.astype(jnp.int32),
+        converged=jnp.array(False), labels=res.labels, e_last=res.energy,
+        carry=carry)
+    st_pending = st._replace(aa=aa_adj, carry=carry)
+    st_conv = st._replace(converged=jnp.array(True), labels=res.labels,
+                          e_last=res.energy, t=st.t + 1, carry=carry)
 
-    def _full(carry):
-        # Line 7: E^t = E(P^t, C^t) — the step's min-dist sum (the paper's
-        # Sec-2.1 low-overhead argument; no re-gather).
-        e_t = e_assign
+    new_inner = _tree_where(conv_now, st_conv,
+                            _tree_where(complete, st_complete, st_pending))
+    new_pending = jnp.logical_and(~conv_now, ~complete)
+    return _BatchedState(new_inner, new_pending)
 
-        # Lines 7-11: dynamic adjustment of m
-        aa_state = anderson.adjust_m(state.aa, e_t, state.e_prev,
-                                     state.e_prev2, cfg.aa)
 
-        # Lines 12-14: keep the accelerated iterate only if it decreases E;
-        # otherwise revert to the fallback iterate C_AU^t = G(C^{t-1}).
-        # The revert's single step supplies labels, energy AND the stats of
-        # the next fallback — the legacy driver re-assigned and then paid a
-        # separate update pass on top.
-        accepted = e_t < state.e_prev
-
-        def _keep(carry):
-            return c_t, res, e_t, carry
-
-        def _revert(carry):
-            res_f, carry = backend.step(x, state.c_au, k, carry)
-            return state.c_au, res_f, res_f.energy, carry
-
-        c_cur, res_cur, e_cur, carry = jax.lax.cond(accepted, _keep, _revert,
-                                                    carry)
-        p_cur = res_cur.labels
-
-        # Line 16: C_AU^{t+1} = Update(X, P^t) — from the already-computed
-        # stats; no further pass over X.
-        c_au_next = backend.centroids_from_step(x, res_cur, k, c_cur)
-
-        # Lines 17-19: Anderson acceleration.
-        g_flat = c_au_next.reshape(-1)
-        f_flat = g_flat - c_cur.reshape(-1)
-        if cfg.accelerated:
-            aa_state, c_next_flat, _, _ = anderson.aa_push_and_solve(
-                aa_state, f_flat, g_flat, cfg.aa)
-            c_next = c_next_flat.reshape(c_cur.shape)
-        else:
-            c_next = c_au_next
-
-        new_state = _LoopState(
-            c=c_next, c_au=c_au_next, p_prev=p_cur,
-            e_prev=e_cur, e_prev2=state.e_prev,
-            aa=aa_state,
-            t=state.t + 1,
-            n_acc=state.n_acc + jnp.where(accepted, 1, 0).astype(jnp.int32),
-            converged=jnp.array(False),
-            labels=p_cur, e_last=e_cur, carry=carry)
-        return new_state, accepted, e_cur
-
-    new_state, accepted, e_cur = jax.lax.cond(converged, _finish, _full,
-                                              carry)
-    return new_state, converged, accepted, e_cur
+def _trip(x, bst: _BatchedState, cfg: KMeansConfig,
+          backend: Backend) -> _BatchedState:
+    """One trip of a single solve: one backend step — at C^t, or at the
+    fallback C_AU^t while a rejected iteration is pending — and the
+    completion it allows.  An accepted iteration costs one trip, a
+    rejected one two; ``t`` advances only when an iteration completes."""
+    st = bst.inner
+    res, carry = backend.step(x, jnp.where(bst.pending, st.c_au, st.c),
+                              cfg.k, st.carry)
+    return _complete_iteration(x, res, carry, bst, cfg, backend)
 
 
 # ---------------------------------------------------------------------------
@@ -239,15 +248,16 @@ def _iteration(x, state: _LoopState, cfg: KMeansConfig, backend: Backend):
 # ---------------------------------------------------------------------------
 #
 # A checkpointable solve runs as a HOST loop over jit'd `lax.while_loop`
-# segments: each segment executes the identical `_iteration` body until a
-# traced boundary (`state.t < seg_end`), so pausing never enters the jit
-# trace and the sequence of executed loop bodies — hence every bit of the
-# trajectory — is exactly that of the uninterrupted single-while_loop run.
-# Snapshots are the raw loop-state pytree via `repro.core.serialize`; the
-# "like" trees below derive the expected structure from the init functions
-# themselves (eval_shape), so the snapshot schema cannot drift from the
-# code.  tests/test_persistence.py proves resume parity against the golden
-# trajectory.
+# segments: each segment runs the identical `_trip` until a traced
+# boundary (`state.t < seg_end`).  A rejected iteration does not advance
+# ``t`` while it is pending, so every segment ends on a completed
+# iteration, pausing never enters the jit trace, and the sequence of
+# trips — hence every bit of the trajectory — is exactly that of the
+# uninterrupted run.  Snapshots are the raw loop-state pytree via
+# `repro.core.serialize`; the "like" trees below derive the expected
+# structure from the init functions themselves (eval_shape), so the
+# snapshot schema cannot drift from the code.  tests/test_persistence.py
+# proves resume parity.
 
 @functools.partial(jax.jit, static_argnames=("cfg", "backend"))
 def _run_segment(x, state: _LoopState, seg_end, cfg: KMeansConfig,
@@ -255,17 +265,18 @@ def _run_segment(x, state: _LoopState, seg_end, cfg: KMeansConfig,
     """Run Algorithm-1 iterations until convergence or t == seg_end.
     ``seg_end`` is a traced scalar, so every segment of a solve reuses one
     compiled program."""
-    def cond(st: _LoopState):
-        return jnp.logical_and(~st.converged, st.t < seg_end)
+    def cond(bst: _BatchedState):
+        return jnp.logical_and(~bst.inner.converged, bst.inner.t < seg_end)
 
-    def body(st: _LoopState):
-        new_state, _, _, _ = _iteration(x, st, cfg, backend)
-        return new_state
+    def body(bst: _BatchedState):
+        return _trip(x, bst, cfg, backend)
 
-    return jax.lax.while_loop(cond, body, state)
+    return jax.lax.while_loop(
+        cond, body, _BatchedState(state, jnp.array(False))).inner
 
 
 _init_state_jit = jax.jit(_init_state, static_argnames=("cfg", "backend"))
+_trip_jit = jax.jit(_trip, static_argnames=("cfg", "backend"))
 
 
 def loop_state_like(x, c0, cfg: KMeansConfig, backend: BackendLike = None):
@@ -486,7 +497,6 @@ def _aa_kmeans_segmented(x, c0, cfg: KMeansConfig, bk: Backend,
 
 
 def aa_kmeans(x: jax.Array, c0: jax.Array, cfg: KMeansConfig,
-              ops: Optional[LloydOps] = None,
               backend: BackendLike = None, *,
               checkpoint_every: int = 0,
               checkpoint_dir=None,
@@ -504,11 +514,9 @@ def aa_kmeans(x: jax.Array, c0: jax.Array, cfg: KMeansConfig,
 
     ``backend`` selects the engine ("dense" | "blocked" | "pallas" |
     "fused" | "hamerly" | "elkan" | "yinyang" | "fused_bounds", a Backend
-    instance, or a distribute()-wrapped one).  ``ops`` is the deprecated
-    LloydOps injection point, adapted via the shim when passed.  The
-    bound family (the last four) threads triangle-inequality bounds
-    through the loop carry — valid across accepted AA jumps and reverts
-    (DESIGN.md §Bounds).
+    instance, or a distribute()-wrapped one).  The bound family (the last
+    four) threads triangle-inequality bounds through the loop carry —
+    valid across accepted AA jumps and reverts (DESIGN.md §Bounds).
 
     Persistence (DESIGN.md §Persistence): ``checkpoint_every=s`` runs the
     solve as a host loop over jit'd s-iteration segments, snapshotting the
@@ -537,7 +545,7 @@ def aa_kmeans(x: jax.Array, c0: jax.Array, cfg: KMeansConfig,
     rows once assignments stabilise, while emitted labels/energies stay
     bit-identical to the unpermuted solve.  The permutation rides the
     backend carry, so checkpoint/resume persists it automatically."""
-    bk = maybe_reorder(resolve_backend(backend, ops, cfg), reorder)
+    bk = maybe_reorder(resolve_backend(backend, cfg=cfg), reorder)
     if checkpoint_every or checkpoint_dir is not None \
             or resume_from is not None or checkpoint_cb is not None \
             or metrics is not None:
@@ -546,40 +554,14 @@ def aa_kmeans(x: jax.Array, c0: jax.Array, cfg: KMeansConfig,
                                     checkpoint_cb, keep_last_n,
                                     keep_every_m, metrics, sync_writes)
 
-    def cond(state: _LoopState):
-        return jnp.logical_and(~state.converged, state.t < cfg.max_iter)
-
-    def body(state: _LoopState):
-        new_state, _, _, _ = _iteration(x, state, cfg, bk)
-        return new_state
-
-    state = _init_state(x, c0, cfg, bk)
-    state = jax.lax.while_loop(cond, body, state)
+    state = _run_segment(x, _init_state(x, c0, cfg, bk),
+                         jnp.asarray(cfg.max_iter, jnp.int32), cfg, bk)
     return _result_from_state(_close(x, state, cfg, bk))
-
-
-def aa_kmeans_jit(x, c0, cfg: KMeansConfig, ops: Optional[LloydOps] = None,
-                  backend: BackendLike = None):
-    fn = jax.jit(lambda xx, cc: aa_kmeans(xx, cc, cfg, ops, backend))
-    return fn(x, c0)
 
 
 # ---------------------------------------------------------------------------
 # Batched driver (many restarts / problems in ONE device program)
 # ---------------------------------------------------------------------------
-
-class _BatchedState(NamedTuple):
-    inner: _LoopState
-    # True while an Algorithm-1 iteration is half-done: the accelerated
-    # iterate was rejected and the fallback step has not run yet.
-    pending: jax.Array
-
-
-def _tree_where(flag, on_true, on_false):
-    """Leaf-wise select on a scalar flag (broadcasts over any leaf shape)."""
-    return jax.tree_util.tree_map(
-        lambda a, b: jnp.where(flag, a, b), on_true, on_false)
-
 
 def _tree_select_rows(mask, on_true, on_false):
     """Leaf-wise per-row select: mask (R,) against leaves of shape (R, ...)."""
@@ -592,88 +574,28 @@ def _is_active(state: _LoopState, max_iter: int):
     return jnp.logical_and(~state.converged, state.t < max_iter)
 
 
-def _complete_batched_iteration(x, res, carry, bst: _BatchedState,
-                                cfg: KMeansConfig,
-                                backend: Backend, w=None) -> _BatchedState:
-    """Per-restart completion logic of the split-phase batched body:
-    everything in Algorithm 1's loop body *after* the backend step.
-    Operates on one restart's (unbatched) state — the driver vmaps it."""
-    st, pending = bst.inner, bst.pending
-    k = cfg.k
-    c_eval = jnp.where(pending, st.c_au, st.c)
-
-    # Line 4 (phase A only): the revert step never checks convergence.
-    # Under per-problem weights the check is MASKED: a padding row (w = 0)
-    # never holds up convergence — its label chases centroids it does not
-    # influence, so it may flip forever on ties while the real rows are
-    # long settled (DESIGN.md §Hierarchy).
-    if w is None:
-        lab_now, lab_prev = res.labels, st.p_prev
-    else:
-        live = w > 0
-        lab_now = jnp.where(live, res.labels, 0)
-        lab_prev = jnp.where(live, st.p_prev, 0)
-    conv_now = jnp.logical_and(~pending,
-                               backend.all_equal(lab_now, lab_prev))
-    # Lines 7-11 (phase A only): m adjusts before the revert decision.
-    aa_adj = anderson.adjust_m(st.aa, res.energy, st.e_prev, st.e_prev2,
-                               cfg.aa)
-    accepted = jnp.logical_and(~pending, res.energy < st.e_prev)
-    complete = jnp.logical_or(pending, accepted)
-
-    # Iteration completion (phase-A-accepted or phase-B): lines 16-19 from
-    # the step's stats.  In phase B the window was already adjusted when
-    # the iterate was rejected, so push into the stored state.
-    aa_for_push = _tree_where(pending, st.aa, aa_adj)
-    c_au_next = backend.centroids_from_step(x, res, k, c_eval)
-    g_flat = c_au_next.reshape(-1)
-    f_flat = g_flat - c_eval.reshape(-1)
-    if cfg.accelerated:
-        aa_pushed, c_next_flat, _, _ = anderson.aa_push_and_solve(
-            aa_for_push, f_flat, g_flat, cfg.aa)
-        c_next = c_next_flat.reshape(st.c.shape)
-    else:
-        aa_pushed, c_next = aa_for_push, c_au_next
-
-    st_complete = _LoopState(
-        c=c_next, c_au=c_au_next, p_prev=res.labels,
-        e_prev=res.energy, e_prev2=st.e_prev, aa=aa_pushed,
-        t=st.t + 1,
-        n_acc=st.n_acc + accepted.astype(jnp.int32),
-        converged=jnp.array(False), labels=res.labels, e_last=res.energy,
-        carry=carry)
-    st_pending = st._replace(aa=aa_adj, carry=carry)
-    st_conv = st._replace(converged=jnp.array(True), labels=res.labels,
-                          e_last=res.energy, t=st.t + 1, carry=carry)
-
-    new_inner = _tree_where(conv_now, st_conv,
-                            _tree_where(complete, st_complete, st_pending))
-    new_pending = jnp.logical_and(~conv_now, ~complete)
-    return _BatchedState(new_inner, new_pending)
-
-
 def _batched_body(x, bst: _BatchedState, cfg: KMeansConfig,
                   backend: Backend, x_batched: bool,
                   w=None) -> _BatchedState:
-    """One *backend step* of Algorithm 1 for the whole batch.
+    """One *backend step* of Algorithm 1 for the whole batch: the
+    single solve's `_trip` with the step batched and the completion
+    vmapped.
 
     Under vmap, ``lax.cond`` lowers to a select that executes both
-    branches, so the sequential ``_iteration`` — whose revert branch
-    contains a second backend step — would cost two passes over X per
-    loop body for *every* restart, accepted or not.  This body instead
-    performs exactly one step and carries an explicit per-restart
-    ``pending`` flag:
+    branches, so a body hiding the revert step in a cond would cost two
+    passes over X per loop body for *every* restart, accepted or not.
+    This body instead performs exactly one step and carries an explicit
+    per-restart ``pending`` flag:
 
       phase A (pending=False): step at C^t.  Converged -> finish.
         Accepted (E^t < E^{t-1}) -> the same step's stats complete the
         iteration.  Rejected -> record the adjusted window and flip to
         pending; the iteration completes next body.
       phase B (pending=True): step at C_AU^t (the fallback), completing
-        the rejected iteration exactly as ``_iteration``'s revert branch.
+        the rejected iteration.
 
     The sequence of backend steps, window pushes and m-adjustments per
-    restart is identical to the sequential driver's, so trajectories
-    match step-for-step; a rejected iteration merely spans two bodies.
+    restart is the single solve's, so trajectories match step-for-step.
     The step itself runs through ``backend.batched_step`` — natively
     batched when the backend provides it (one shared-X einsum + matmul
     stats for dense), vmapped otherwise; only the cheap completion logic
@@ -685,11 +607,11 @@ def _batched_body(x, bst: _BatchedState, cfg: KMeansConfig,
                                       x_batched=x_batched, w=w)
     if w is None:
         return jax.vmap(
-            lambda xx, r, cr, ob: _complete_batched_iteration(
+            lambda xx, r, cr, ob: _complete_iteration(
                 xx, r, cr, ob, cfg, backend),
             in_axes=(0 if x_batched else None, 0, 0, 0))(x, res, carry, bst)
     return jax.vmap(
-        lambda xx, r, cr, ob, ww: _complete_batched_iteration(
+        lambda xx, r, cr, ob, ww: _complete_iteration(
             xx, r, cr, ob, cfg, backend, w=ww),
         in_axes=(0 if x_batched else None, 0, 0, 0, 0))(x, res, carry, bst,
                                                         w)
@@ -740,7 +662,6 @@ def batched_state_like(x, c0s, cfg: KMeansConfig,
 
 
 def aa_kmeans_batched(x: jax.Array, c0s: jax.Array, cfg: KMeansConfig,
-                      ops: Optional[LloydOps] = None,
                       backend: BackendLike = None, *,
                       checkpoint_every: int = 0,
                       checkpoint_dir=None,
@@ -800,7 +721,7 @@ def aa_kmeans_batched(x: jax.Array, c0s: jax.Array, cfg: KMeansConfig,
         raise ValueError(
             f"weights must be (R, N) = ({c0s.shape[0]}, {x.shape[-2]}); "
             f"got {weights.shape}")
-    bk = maybe_reorder(resolve_backend(backend, ops, cfg), reorder)
+    bk = maybe_reorder(resolve_backend(backend, cfg=cfg), reorder)
     x_axis = 0 if x.ndim == 3 else None
 
     if checkpoint_every or checkpoint_dir is not None \
@@ -1280,8 +1201,6 @@ def split_bound_phases(accepted, bound_stats):
 
 
 def aa_kmeans_traced(x: jax.Array, c0: jax.Array, cfg: KMeansConfig,
-                     ops: Optional[LloydOps] = None,
-                     jit_iteration: bool = True,
                      backend: BackendLike = None,
                      warmup: bool = False,
                      metrics=None,
@@ -1293,45 +1212,46 @@ def aa_kmeans_traced(x: jax.Array, c0: jax.Array, cfg: KMeansConfig,
     bound backends — the same numbers the returned trace accumulates,
     streamed live instead of collected at the end.
 
-    ``warmup=True`` compiles the init/iteration computations on a throwaway
+    ``warmup=True`` compiles the init and trip computations on a throwaway
     run before the timer starts, so ``wall_time_s`` measures steady-state
     execution rather than jit compilation — the quantity the paper's
     Table 3 wall-times report.  (Both jitted functions are keyed on static
     (cfg, backend) and the argument shapes, so the warm-up populates
     exactly the cache the timed loop hits.)
 
+    The loop runs `aa_kmeans`'s trip (`_trip`) from Python and records an
+    iteration when a trip completes one: a rejected iteration's first
+    trip leaves it pending and records nothing.
+
     ``reorder=`` enables the locality engine exactly as in ``aa_kmeans``;
     the trace's ``bound_phases`` then shows the converged-phase skip the
     reordering unlocked (the flat average would dilute it — see
     `split_bound_phases`).
     """
-    bk = maybe_reorder(resolve_backend(backend, ops, cfg), reorder)
-    iter_fn = _iteration
-    if jit_iteration:
-        iter_fn = jax.jit(_iteration, static_argnames=("cfg", "backend"))
-    init_fn = jax.jit(_init_state, static_argnames=("cfg", "backend")) \
-        if jit_iteration else _init_state
+    bk = maybe_reorder(resolve_backend(backend, cfg=cfg), reorder)
+
+    def start():
+        return _BatchedState(_init_state_jit(x, c0, cfg, bk),
+                             jnp.array(False))
 
     if warmup:
-        ws = init_fn(x, c0, cfg, bk)
-        ws, _, _, _ = iter_fn(x, ws, cfg, bk)
-        jax.block_until_ready(ws.c)
+        jax.block_until_ready(_trip_jit(x, start(), cfg, bk).inner.c)
 
     from repro.core.backends.bounds import extract_stats
 
     mx = as_metrics(metrics)
     t0 = time.perf_counter()
-    state = init_fn(x, c0, cfg, bk)
+    bst = start()
     energies, m_vals, acc, bstats = [], [], [], []
-    converged = False
-    while not converged and int(state.t) < cfg.max_iter:
-        state, conv, accepted, e_t = iter_fn(x, state, cfg, bk)
-        converged = bool(conv)
-        if converged:
-            break
-        energies.append(float(e_t))
+    while not bool(bst.inner.converged) and int(bst.inner.t) < cfg.max_iter:
+        was_pending = bool(bst.pending)
+        bst = _trip_jit(x, bst, cfg, bk)
+        if bool(bst.inner.converged) or bool(bst.pending):
+            continue
+        state = bst.inner
+        energies.append(float(state.e_last))
         m_vals.append(int(state.aa.m))
-        acc.append(bool(accepted))
+        acc.append(not was_pending)
         scalars = {"energy": energies[-1], "m": float(m_vals[-1]),
                    "accepted": float(acc[-1])}
         bs = extract_stats(state.carry)
@@ -1340,9 +1260,10 @@ def aa_kmeans_traced(x: jax.Array, c0: jax.Array, cfg: KMeansConfig,
                            "skipped_frac": float(bs.skipped_frac)})
             scalars.update(bstats[-1])
         mx.log_scalars(len(energies), scalars)
+    state = bst.inner
+    converged = bool(state.converged)
     if not converged:
-        close = _close_jit if jit_iteration else _close
-        state = close(x, state, cfg, bk)
+        state = _close_jit(x, state, cfg, bk)
     jax.block_until_ready(state.c)
     wall = time.perf_counter() - t0
 

@@ -7,14 +7,10 @@ The paper (Zhang et al., 2018) treats one Lloyd iteration — assignment step
 
 whose residual F(C) = G(C) - C vanishes at a local minimum of the K-Means
 energy (Eq. 1).  This module provides the three primitives (assign / update /
-energy) as pure, jit-able JAX functions plus an `Ops` container so that the
-same Algorithm-1 driver (kmeans.py) can run with
-
-  * the dense single-device ops below,
-  * the Pallas TPU kernels (repro.kernels.ops), or
-  * the shard_map distributed ops (repro.core.distributed)
-
-without any change to the acceleration logic.
+energy) as pure, jit-able JAX functions.  The Algorithm-1 drivers
+(kmeans.py) reach them through a step-primitive `Backend`
+(repro.core.backends): dense, as Pallas TPU kernels, or sharded over a
+mesh, without any change to the acceleration logic.
 
 Hardware adaptation note (see DESIGN.md): the paper's CPU implementation uses
 Hamerly's bound-based assignment to skip distance computations.  Bound
@@ -27,9 +23,8 @@ provided in `hamerly.py` for completeness and CPU benchmarking.
 
 from __future__ import annotations
 
-import dataclasses
 from functools import partial
-from typing import Callable, NamedTuple, Optional
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -170,52 +165,11 @@ def energy_from_mindist(min_sqdist: jax.Array) -> jax.Array:
     return jnp.sum(min_sqdist)
 
 
-# ---------------------------------------------------------------------------
-# Ops container — dependency injection point for kernels / distribution
-# ---------------------------------------------------------------------------
-
-@dataclasses.dataclass(frozen=True)
-class LloydOps:
-    """DEPRECATED dependency-injection container (see DESIGN.md §Backends).
-
-    Superseded by `repro.core.backends.Backend`, whose single-pass
-    ``step()`` primitive lets the driver run one pass over X per accepted
-    iteration; separate assign/update call sites cannot express that.
-    Passing a LloydOps to the solvers still works — it is adapted through
-    `repro.core.backends.from_lloyd_ops` with the legacy two-pass cost.
-
-    assign_fn(x, c)            -> AssignResult
-    update_fn(x, labels, k, c) -> new centroids (K,d)
-    energy_fn(x, c, labels)    -> scalar energy
-    all_equal_fn(a, b)         -> scalar bool (assignments identical;
-                                  distributed backends psum-reduce this)
-    """
-    assign_fn: Callable = assign
-    update_fn: Callable = update
-    energy_fn: Callable = energy
-    all_equal_fn: Callable = lambda a, b: jnp.all(a == b)
-    # scalar cross-shard reduction (distributed backends psum); the solver
-    # computes E(P^t, C^t) as sum(min_sqdist) reusing the assignment — the
-    # paper's O(N) overhead argument (Sec 2.1 part ii) — then reduces it.
-    reduce_scalar: Callable = lambda x: x
-
-    def g_map(self, x: jax.Array, c: jax.Array, k: int):
-        """One application of the fixed-point map G = Update o Assign.
-
-        Returns (G(c), labels, min_sqdist)."""
-        res = self.assign_fn(x, c)
-        c_new = self.update_fn(x, res.labels, k, c)
-        return c_new, res
-
-
-DENSE_OPS = LloydOps()
-
-
-def lloyd_iteration(x: jax.Array, c: jax.Array, k: int,
-                    ops: LloydOps = DENSE_OPS):
+def lloyd_iteration(x: jax.Array, c: jax.Array, k: int):
     """One classical Lloyd iteration; returns (C', labels, energy(P, C))."""
-    c_new, res = ops.g_map(x, c, k)
-    return c_new, res.labels, energy_from_mindist(res.min_sqdist)
+    res = assign(x, c)
+    return (update(x, res.labels, k, c), res.labels,
+            energy_from_mindist(res.min_sqdist))
 
 
 @partial(jax.jit, static_argnames=("k", "max_iter"))

@@ -335,7 +335,6 @@ def reorder_backend(inner: Backend,
                    energy_fn=inner.energy_fn,
                    all_equal_fn=inner.all_equal_fn,
                    init_carry_fn=init_carry_fn,
-                   finalize_fn=inner.finalize_fn,
                    precision=inner.precision)
 
 

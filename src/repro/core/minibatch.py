@@ -142,7 +142,8 @@ def minibatch_iteration(x_chunk, w, x_val, state: MiniBatchState,
                         cfg: MiniBatchConfig, backend: Backend):
     """One chunk step of streaming Algorithm 1.
 
-    Structure mirrors `kmeans._iteration` line for line, with E replaced
+    Structure mirrors Algorithm 1's loop body (`kmeans._trip` and
+    `kmeans._complete_iteration`), with E replaced
     by the validation-chunk energy and G by the decayed-running-stats map:
     guard (accept/revert) -> m-adjustment -> one weighted pass over the
     chunk -> running-stat update -> Anderson push/solve.

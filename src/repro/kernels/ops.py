@@ -10,19 +10,17 @@ consumed through the step primitive, so Algorithm 1 reads X exactly once
 per accepted iteration — at arbitrary K, since the v2 kernel k-tiles the
 centroid stream (DESIGN.md §Kernels-v2; there is no VMEM fallback path).
 Row weights and the leading-R batch axis of the kernels are exposed here
-as optional arguments.  `pallas_lloyd_ops()` remains as the deprecated
-LloydOps adapter for code still injecting assign/update separately.
+as optional arguments.
 """
 
 from __future__ import annotations
 
 import jax
-import jax.numpy as jnp
 
 from repro.core.backends import fused_backend, pallas_backend  # noqa: F401
 from repro.core.backends.pallas import (FUSED_MAX_KD,          # noqa: F401
                                         FUSED_VMEM_BYTES)
-from repro.core.lloyd import AssignResult, LloydOps, update_from_sums
+from repro.core.lloyd import update_from_sums
 from repro.kernels import ref
 from repro.kernels.assignment import assignment_pallas
 from repro.kernels.fused_lloyd import fused_lloyd_pallas
@@ -71,31 +69,3 @@ def fused_step(x: jax.Array, c: jax.Array, *, use_pallas: bool = True):
         x, c, use_pallas=use_pallas)
     c_next = update_from_sums(sums, counts, c.astype(sums.dtype))
     return c_next.astype(c.dtype), labels, energy
-
-
-# ---------------------------------------------------------------------------
-# Deprecated LloydOps adapter — prefer get_backend("pallas"/"fused")
-# ---------------------------------------------------------------------------
-
-def pallas_lloyd_ops() -> LloydOps:
-    """Algorithm-1 ops backed by the separate assignment/update kernels.
-
-    Deprecated: the step-driven solver consumes `pallas_backend()` /
-    `fused_backend()` directly (one pass per accepted iteration); this
-    container remains for callers injecting assign/update separately."""
-
-    def assign_fn(x, c):
-        labels, mind = assignment(x, c)
-        return AssignResult(labels, mind)
-
-    def update_fn(x, labels, k, c_prev):
-        sums, counts = cluster_update(x, labels, k)
-        return update_from_sums(sums, counts,
-                                c_prev.astype(sums.dtype)).astype(c_prev.dtype)
-
-    def energy_fn(x, c, labels):
-        diff = x.astype(jnp.float32) - c.astype(jnp.float32)[labels]
-        return jnp.sum(diff * diff)
-
-    return LloydOps(assign_fn=assign_fn, update_fn=update_fn,
-                    energy_fn=energy_fn)

@@ -2,8 +2,9 @@
 regression in tests/test_golden.py.
 
 `compute_trajectory()` runs a small fixed-seed full-batch AA solve on the
-dense backend through the jitted `_iteration` body and records the
-per-iteration post-revert energies and labels plus the final centroids —
+dense backend through the solver's jitted trip (`_trip`: one backend step
+and the completion it allows) and records, for every completed
+iteration, the post-revert energy and labels, plus the final centroids —
 exactly the quantities whose silent drift the golden test guards.
 
 Regenerating the file is an *intentional numerics change* and belongs in
@@ -40,17 +41,18 @@ def compute_trajectory():
     cfg = KMeansConfig(k=K, max_iter=MAX_ITER)
     backend = get_backend("dense")
 
-    init_fn = jax.jit(KM._init_state, static_argnames=("cfg", "backend"))
-    iter_fn = jax.jit(KM._iteration, static_argnames=("cfg", "backend"))
-
-    state = init_fn(x, c0, cfg, backend)
+    bst = KM._BatchedState(KM._init_state_jit(x, c0, cfg, backend),
+                           jnp.array(False))
     energies, labels = [], []
-    for _ in range(MAX_ITER):
-        state, conv, _, e_t = iter_fn(x, state, cfg, backend)
-        if bool(conv):
+    while int(bst.inner.t) < MAX_ITER:
+        bst = KM._trip_jit(x, bst, cfg, backend)
+        if bool(bst.inner.converged):
             break
-        energies.append(np.asarray(e_t))
-        labels.append(np.asarray(state.labels))
+        if bool(bst.pending):       # a rejected iterate: not done yet
+            continue
+        energies.append(np.asarray(bst.inner.e_last))
+        labels.append(np.asarray(bst.inner.labels))
+    state = bst.inner
     return {
         "energies": np.stack(energies),              # (T,) f32, exact bits
         "labels": np.stack(labels).astype(np.int32),  # (T, N)

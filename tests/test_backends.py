@@ -10,7 +10,8 @@
 4. distribute() combinator: the psum wrapping is semantics-preserving for
    any local backend (single-device shard_map check; the multi-device
    version lives in test_distributed).
-5. Legacy LloydOps injection still works through the deprecation shim.
+5. Backend selection: a registry name, a Backend or None; anything else
+   is refused.
 """
 
 import jax
@@ -158,18 +159,19 @@ def test_distributed_energy_op_reduces_once():
     np.testing.assert_allclose(float(e), e_ref, rtol=1e-5)
 
 
-def test_lloyd_ops_adapter_is_memoised():
-    from repro.core.lloyd import LloydOps
-    ops = LloydOps()
-    assert B.from_lloyd_ops(ops) is B.from_lloyd_ops(ops)
+def test_resolve_backend_rejects_junk():
+    """Only a registry name, a Backend or None names an engine: an object
+    shaped like the old assign/update container is junk too."""
+    from types import SimpleNamespace
 
-
-def test_resolve_backend_accepts_lloyd_ops_and_rejects_junk():
     from repro.core.kmeans import resolve_backend
-    from repro.core.lloyd import LloydOps
-    assert resolve_backend(LloydOps()).name == "lloyd-ops-shim"
-    with pytest.raises(TypeError):
-        resolve_backend(object())
+    ops_shaped = SimpleNamespace(
+        assign_fn=lambda x, c: None, update_fn=lambda x, p, k, c: c,
+        energy_fn=lambda x, c, p: 0.0, all_equal_fn=lambda a, b: True,
+        reduce_scalar=lambda s: s)
+    for junk in (object(), ops_shaped, 3):
+        with pytest.raises(TypeError):
+            resolve_backend(junk)
 
 
 def test_reregistering_backend_invalidates_cache():
@@ -184,17 +186,6 @@ def test_reregistering_backend_invalidates_cache():
         from repro.core.backends import base as _base
         _base._REGISTRY.pop("tmp-test-backend", None)
         _base._INSTANCES.pop(("tmp-test-backend", ()), None)
-
-
-def test_legacy_lloyd_ops_shim(fixture):
-    from repro.kernels.ops import pallas_lloyd_ops
-    x, c0 = fixture
-    cfg = KMeansConfig(k=K, max_iter=300)
-    ref = aa_kmeans(x, c0, cfg)
-    res = aa_kmeans(x, c0, cfg, ops=pallas_lloyd_ops())
-    assert int(res.n_iter) == int(ref.n_iter)
-    np.testing.assert_allclose(float(res.energy), float(ref.energy),
-                               rtol=1e-5)
 
 
 def test_precision_policy(fixture):

@@ -14,7 +14,7 @@ import pytest
 
 from repro.core.api import AAKMeans
 from repro.core.init_schemes import batched_init
-from repro.core.kmeans import (KMeansConfig, _init_state, _iteration,
+from repro.core.kmeans import (KMeansConfig, _init_state, _run_segment,
                                _result_from_state, aa_kmeans,
                                aa_kmeans_batched, aa_kmeans_batched_loop,
                                resolve_backend)
@@ -37,13 +37,8 @@ def _seeds(x, k, restarts, seed=0, init="random"):
 def _unclosed(x, c0, cfg):
     """The solver loop's exit state, before any closing evaluation: the
     single-device program without `_close`."""
-    @jax.jit
-    def run(x, c0):
-        st = _init_state(x, c0, cfg, BK)
-        return jax.lax.while_loop(
-            lambda s: ~s.converged & (s.t < cfg.max_iter),
-            lambda s: _iteration(x, s, cfg, BK)[0], st)
-    return run(x, c0)
+    return jax.jit(lambda x, c0: _run_segment(
+        x, _init_state(x, c0, cfg, BK), cfg.max_iter, cfg, BK))(x, c0)
 
 
 def _unclosed_batched(x, c0s, cfg):

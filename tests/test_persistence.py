@@ -33,7 +33,8 @@ from repro.core.backends import Precision, get_backend
 from repro.core.backends.dense import dense_backend
 from repro.core.init_schemes import kmeanspp_init
 from repro.core.kmeans import (KMeansConfig, aa_kmeans, aa_kmeans_batched,
-                               aa_kmeans_minibatch, select_best)
+                               aa_kmeans_minibatch, aa_kmeans_traced,
+                               select_best)
 from repro.core.lloyd import assign, cluster_sums, weighted_cluster_sums
 from repro.core.minibatch import MiniBatchConfig, minibatch_init
 from repro.data.streaming import chunk_dataset, split_validation
@@ -250,6 +251,78 @@ def test_checkpointed_call_refuses_jit(golden_problem):
     x, c0, cfg = golden_problem
     with pytest.raises(ValueError, match="host-side segment loop"):
         jax.jit(lambda a, b: aa_kmeans(a, b, cfg, checkpoint_every=2))(x, c0)
+
+
+@pytest.fixture(scope="module")
+def rejecting_problem():
+    """21 iterations, five of them rejected (two pairs back to back)."""
+    x = jnp.asarray(make_blobs(500, 3, 8, seed=2, spread=1.2))
+    c0 = kmeanspp_init(jax.random.PRNGKey(2), x, 8)
+    return x, c0, KMeansConfig(k=8, max_iter=200)
+
+
+def test_segments_end_on_completed_iterations(rejecting_problem, tmp_path):
+    """A rejected iteration spans two trips of the loop, but a segment
+    never ends between them: at checkpoint_every=1 every snapshot is one
+    iteration on from the last, and a resume from the snapshot right
+    after a rejected iteration finishes as the uninterrupted solve."""
+    x, c0, cfg = rejecting_problem
+    ref = aa_kmeans(x, c0, cfg)
+    d = tmp_path / "run"
+    states = []
+
+    def record(st, t):
+        # checked as the solve runs: a segment that stopped between a
+        # rejected iterate and its fallback would never advance t again
+        assert t == len(states) + 1, (t, len(states))
+        states.append((t, st))
+
+    aa_kmeans(x, c0, cfg, checkpoint_every=1, checkpoint_dir=d,
+              sync_writes=True, checkpoint_cb=record)
+    ts = [t for t, _ in states]
+    assert [int(st.t) for _, st in states] == ts
+    snaps = {int(p.stem.split("_")[1]): p for p in d.glob("it_*.npz")}
+    assert sorted(snaps) == ts
+    n_acc = [0] + [int(st.n_acc) for _, st in states]
+    rejected = [t for t in ts if n_acc[t] == n_acc[t - 1]
+                and not bool(states[t - 1][1].converged)]
+    assert rejected, "the problem must reject some iterate"
+    res = aa_kmeans(x, c0, cfg, resume_from=snaps[rejected[0]])
+    _bits_equal(res.energy, ref.energy)
+    np.testing.assert_array_equal(np.asarray(res.labels),
+                                  np.asarray(ref.labels))
+    _bits_equal(res.centroids, ref.centroids)
+    assert int(res.n_iter) == int(ref.n_iter)
+    assert int(res.n_accepted) == int(ref.n_accepted)
+
+
+@pytest.mark.parametrize("backend", ["dense", "hamerly"])
+def test_traced_and_segmented_walk_the_same_iterations(rejecting_problem,
+                                                       backend):
+    """The Python-loop driver records an iteration when a trip completes
+    one: its energies are, bit for bit, the e_last of each segment of a
+    checkpoint_every=1 solve, and its result is aa_kmeans's."""
+    x, c0, cfg = rejecting_problem
+    tr = aa_kmeans_traced(x, c0, cfg, backend=backend)
+    assert not all(tr.accepted)
+    states = []
+    aa_kmeans(x, c0, cfg, backend=backend, checkpoint_every=1,
+              checkpoint_cb=lambda st, t: states.append(st))
+    live = [st for st in states if not bool(st.converged)]
+    _bits_equal(np.asarray(tr.energies, np.float32),
+                np.stack([np.asarray(st.e_last) for st in live]),
+                "traced energies")
+    assert tr.accepted == [bool(b - a) for a, b in zip(
+        [0] + [int(st.n_acc) for st in live[:-1]],
+        [int(st.n_acc) for st in live])]
+    ref = aa_kmeans(x, c0, cfg, backend=backend)
+    assert int(tr.result.n_iter) == int(ref.n_iter)
+    assert int(tr.result.n_accepted) == int(ref.n_accepted)
+    assert bool(tr.result.converged) == bool(ref.converged)
+    _bits_equal(tr.result.energy, ref.energy)
+    np.testing.assert_array_equal(np.asarray(tr.result.labels),
+                                  np.asarray(ref.labels))
+    _bits_equal(tr.result.centroids, ref.centroids)
 
 
 def test_batched_resume_is_bit_identical(rng, tmp_path):
