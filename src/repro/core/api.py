@@ -23,6 +23,7 @@ mesh-fitted model keeps using its mesh for predict/transform.
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import functools
 import math
@@ -120,6 +121,12 @@ def mesh_fit_program_traces() -> int:
     return _MESH_FIT_PROGRAM_TRACES
 
 
+# chunks of `_chunked_rows_apply` on the device at once: chunk i+1 is put
+# and dispatched before the host waits on chunk i
+_ROWS_IN_FLIGHT = 2
+_ROWS_CHUNKS_AHEAD = 0
+
+
 class NotFittedError(RuntimeError):
     """Inference was requested on an estimator with no fitted state.
 
@@ -156,10 +163,18 @@ def _chunked_rows_apply(model, x, kind, fn, out_dtype, out_cols=None,
     """Run ``fn(x_chunk, centroids, *extras) -> per-row output`` jitted,
     chunk by chunk, into a HOST (numpy) array — the single-device serving
     path shared by both estimators.  The chunking bounds the device
-    footprint for host-sized X (an (N, K) transform of such an X would
-    not fit back on device either, hence the numpy result), and the
-    jitted fn is cached on the model per (kind, backend) so a serving
-    loop pays dispatch/tracing once instead of eager per-call overhead.
+    footprint for host-sized X to ``_ROWS_IN_FLIGHT`` (two) chunks' input
+    and output (an (N, K) transform of such an X would not fit back on
+    device either, hence the numpy result), and the jitted fn is cached
+    on the model per (kind, backend) so a serving loop pays
+    dispatch/tracing once instead of eager per-call overhead.
+
+    The loop keeps one chunk ahead: chunk i+1 is put and its program
+    dispatched before the host blocks on chunk i's answer, so the copy
+    in, the copy back and the host's staging of one chunk hide behind
+    the previous chunk's program, and the device runs one chunk's
+    program after the other with no host round trip between them.
+    Answers are written into the host array in chunk order.
 
     Every chunk fed to the jitted fn has EXACTLY ``step`` rows: the tail
     chunk is padded with copies of its last row and the padding sliced
@@ -168,6 +183,7 @@ def _chunked_rows_apply(model, x, kind, fn, out_dtype, out_cols=None,
     is precisely the varying-batch-size pattern a request queue
     produces.  Extras are passed through to the fn unchanged, so index
     arrays can be swapped (same shapes) without invalidating the cache."""
+    global _ROWS_CHUNKS_AHEAD
     cache = model.__dict__.setdefault("_local_runners", {})
     run = cache.get((kind, model.backend))
     if run is None:
@@ -177,10 +193,20 @@ def _chunked_rows_apply(model, x, kind, fn, out_dtype, out_cols=None,
     c = jnp.asarray(model.centroids_)
     shape = (n,) if out_cols is None else (n, out_cols)
     out = np.empty(shape, out_dtype)
+    in_flight = collections.deque()     # (row, rows, answer), unfetched
+
+    def fetch():
+        # waits for the oldest chunk's copy in, program and copy back
+        i, m, yc = in_flight.popleft()
+        with jax.profiler.TraceAnnotation("repro.rows.fetch"):
+            out[i:i + m] = np.asarray(yc)[:m]
+
     for i in range(0, n, step):
+        if in_flight:
+            _ROWS_CHUNKS_AHEAD += 1
         # put stages the chunk on the host; the runtime relayouts and
-        # copies it to the device after the span closes, so that wait
-        # falls inside fetch, with the program's run and the copy back
+        # copies it to the device after the span closes, while the
+        # program of the chunk before it runs
         with jax.profiler.TraceAnnotation("repro.rows.put"):
             xc = jnp.asarray(x[i:i + step])
             m = xc.shape[0]
@@ -188,10 +214,21 @@ def _chunked_rows_apply(model, x, kind, fn, out_dtype, out_cols=None,
                 xc = jnp.concatenate(
                     [xc, jnp.repeat(xc[-1:], step - m, axis=0)])
         with jax.profiler.TraceAnnotation("repro.rows.run"):
-            yc = run(xc, c, *extras)
-        with jax.profiler.TraceAnnotation("repro.rows.fetch"):
-            out[i:i + m] = np.asarray(yc)[:m]
+            in_flight.append((i, m, run(xc, c, *extras)))
+        if len(in_flight) == _ROWS_IN_FLIGHT:
+            fetch()
+    while in_flight:
+        fetch()
     return out
+
+
+def rows_chunks_ahead() -> int:
+    """How many chunks of the chunked inference path
+    (`_chunked_rows_apply`) have been put in this process while an
+    earlier chunk's answer was still unfetched: n_chunks - 1 per call,
+    so over the number of chunks, the share whose copy and dispatch ran
+    ahead of the host's wait."""
+    return _ROWS_CHUNKS_AHEAD
 
 
 # -- shared inference paths (both estimators) --------------------------------
@@ -869,13 +906,6 @@ class MiniBatchAAKMeans:
                 "this MiniBatchAAKMeans instance has no fitted centroids; "
                 "call fit() or partial_fit() (or load() a fitted "
                 "artifact) first")
-
-    def _chunked_apply(self, x, kind, fn, out_dtype, out_cols=None,
-                       chunk_size=None):
-        """Jitted chunk-by-chunk apply into a host array — shared with
-        AAKMeans via the module-level `_chunked_rows_apply`."""
-        return _chunked_rows_apply(self, x, kind, fn, out_dtype,
-                                   out_cols=out_cols, chunk_size=chunk_size)
 
     def build_serving_index(self, n_candidates: Optional[int] = None,
                             n_groups: Optional[int] = None,

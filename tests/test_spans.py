@@ -67,8 +67,10 @@ def test_predict_emits_put_run_fetch_per_chunk(trace_lib, tmp_path):
     got = []
     names = _traced(trace_lib, tmp_path, lambda: got.append(
         model.predict(rows, chunk_size=100)))
-    assert names == ["repro.rows.put", "repro.rows.run",
-                     "repro.rows.fetch"] * 3
+    # one chunk ahead: chunk i+1 is put and dispatched before chunk i's
+    # answer is fetched
+    put, run, fetch = "repro.rows.put", "repro.rows.run", "repro.rows.fetch"
+    assert names == [put, run, put, run, fetch, put, run, fetch, fetch]
     np.testing.assert_array_equal(got[0], want)
     assert got[0].shape == (250,)
 
